@@ -541,6 +541,10 @@ def _bench_sharded_apply(entries: List[Dict], speedups: Dict,
 
     repo = os.path.dirname(BENCH_JSON)
     env = dict(os.environ)
+    # virtual host devices; explicitly the CPU backend, because this
+    # process may already hold the accelerator and a second one would wait
+    # on it or fail
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8")
     env["PYTHONPATH"] = (os.path.join(repo, "src") + os.pathsep

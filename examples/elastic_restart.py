@@ -14,7 +14,6 @@ PHASE_CODE = r"""
 import os, sys, json
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import compat
 from repro.configs.base import TrainConfig
 from repro.configs.paper_models import GPT2_BASE
 from repro.data import GlobalBatchLoader
@@ -32,7 +31,7 @@ tcfg = TrainConfig(steps=40, warmup_steps=4, lr=1e-3)
 devs = jax.devices()
 mesh = jax.sharding.Mesh(np.array(devs), ("data",))
 dp = len(devs)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     params = init_params(cfg, jax.random.PRNGKey(0))
     pspecs = params_pspecs(params, model_size=1, dp_size=dp)
     psh = named_shardings(pspecs, mesh)

@@ -128,16 +128,19 @@ def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
                 engine=engine),
         argnums=0)
 
-    def sgd_step(carry, batch):
+    def sgd_step(small, carry, batch):
         ligo, mom = carry
-        loss, g = grad_fn(ligo, small_params, batch=batch)
+        loss, g = grad_fn(ligo, small, batch=batch)
         mom = jax.tree.map(lambda m, gg: momentum * m + gg, mom, g)
         ligo = jax.tree.map(lambda p, m: p - lr * m, ligo, mom)
         return (ligo, mom), loss
 
-    def run_chunk(ligo, mom, batches):
+    # the source params are an argument, not a closure: captured, they
+    # would be baked into the chunk program as constants
+    def run_chunk(ligo, mom, small, batches):
         TRACE_COUNTS.inc("train_ligo")
-        (ligo, mom), losses = jax.lax.scan(sgd_step, (ligo, mom), batches)
+        (ligo, mom), losses = jax.lax.scan(partial(sgd_step, small),
+                                           (ligo, mom), batches)
         return ligo, mom, losses
 
     if steps <= 0:
@@ -215,7 +218,8 @@ def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
                 lambda x: jax.ShapeDtypeStruct((n_chunk,) + x.shape,
                                                x.dtype), batch_tree)
             m = costs.measure_jitted(
-                f"ligo_chunk[{cfg2.name}]", run_chunk, ligo, mom, stacked,
+                f"ligo_chunk[{cfg2.name}]", run_chunk, ligo, mom,
+                small_params, stacked,
                 modelled_flops=led_state["fps_model"] * n_chunk,
                 n_devices=led_nd, per_call_units=n_chunk)
             if m is not None:
@@ -251,7 +255,8 @@ def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
             if ledger is not None and led_state["tokens"] is None:
                 _ledger_prepare(raw[0], n)
             batches = _stack_batches(raw)
-            ligo, mom, chunk_losses = run_chunk(ligo, mom, batches)
+            ligo, mom, chunk_losses = run_chunk(ligo, mom, small_params,
+                                                batches)
             chunk_losses = [float(l) for l in chunk_losses]
             losses.extend(chunk_losses)
         h_chunk.observe(sp_chunk.dur_ms or 0.0)

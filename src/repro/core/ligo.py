@@ -220,7 +220,7 @@ def apply_ligo(ligo: Params, small: Params, cfg1: ModelConfig,
     pjit-compiled with ``params_pspecs``-derived in/out shardings (expanders
     replicated, leaf stacks sharded like their model weights) and the fused
     path runs per shard under ``shard_map``. Default: the ambient mesh
-    installed by ``compat.set_mesh`` when one exists — the train/serve
+    installed by ``jax.set_mesh`` when one exists — the train/serve
     drivers grow distributed without passing anything.
 
     ``square=True`` applies the *elementwise-squared* operator: every

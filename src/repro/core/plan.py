@@ -30,8 +30,10 @@ the group dim G and expert dim E fold into the kernel grid (one launch per
 group, not per leaf) and non-128-aligned dims run on cdiv grids with
 in-kernel zero-masked ragged tiles, so vocab-projection-sized and odd-head
 shapes are no longer rejected. The only exclusions are degenerate dims and
-groups whose backward-kernel scratch accumulators would overflow the VMEM
-budget (see :func:`repro.kernels.fused_vmem_bytes`).
+groups whose blocks and accumulators would overflow the kernels' VMEM limit
+(see :func:`repro.kernels.fused_vmem_bytes`).
+:meth:`GrowthPlan.kernel_groups` counts the groups that take the fused
+route; the others run the einsum contractions.
 
 The backward pass — the LiGO phase's hot loop, differentiated on every SGD
 step — is a *single* fused Pallas pass over the ``dP`` tiles
@@ -61,7 +63,7 @@ the fused Pallas path runs the grouped custom_vjp **per shard** under
 ``shard_map`` (:func:`repro.kernels.ligo_blend_expand_grouped_sharded`): the
 kernel only contracts the blend (L1) and expansion (A) dims, so sharding the
 trailing output dim (or the group dim) needs no cross-device traffic. Callers
-that sit under an ambient mesh (``compat.set_mesh`` — the train/serve
+that sit under an ambient mesh (``jax.set_mesh`` — the train/serve
 drivers) pick this up automatically through ``apply_ligo``.
 
 Operator composition
@@ -85,6 +87,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import sys
 from collections import Counter
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
@@ -99,7 +102,7 @@ from repro.core.ligo import (_flatten, _kind_counts, _unflatten,
                              resolve_expander)
 from repro.distributed.sharding import (named_shardings, params_pspecs,
                                         physical_spec)
-from repro.kernels.ops import (fused_eligible,
+from repro.kernels.ops import (LAUNCH_COUNTS, fused_eligible,
                                ligo_blend_expand_grouped_sharded,
                                ligo_blend_expand_grouped_vjp)
 
@@ -191,7 +194,7 @@ def _best_order(ops_present, L1: int, L2: int, extra: int, a: int, b: int,
 
 
 def _plan_group(kind: str, stacked: bool, paths, shape, in_e, out_e,
-                vec: bool, L2: int, cfg1, cfg2) -> LeafGroup:
+                vec: bool, L2: int, cfg1, cfg2, itemsize: int) -> LeafGroup:
     """Choose contraction order + kernel eligibility from static shapes."""
     in_ref = None if in_e is None else (_expr_key(in_e), "in")
     out_ref = None if out_e is None else (_expr_key(out_e), "out")
@@ -218,9 +221,10 @@ def _plan_group(kind: str, stacked: bool, paths, shape, in_e, out_e,
     order = _best_order(ops_present, L1, L2, extra, a, b, i, j)
     # Fused Pallas eligibility: stacked (L1, a, b) or MoE (L1, E, a, b) with
     # an in-expander — G/E fold into the grid, ragged dims are masked
-    # in-kernel, so only the VMEM scratch budget can reject a real shape.
+    # in-kernel, so only the kernels' VMEM limit can reject a real shape.
     kernel_ok = (blended and in_e is not None and len(shape) in (3, 4)
-                 and fused_eligible(L1, L2, extra, i, a, b))
+                 and fused_eligible(L1, L2, extra, i, a, b, G=len(paths),
+                                    itemsize=itemsize))
     return LeafGroup(kind, stacked, tuple(paths), tuple(shape), in_ref,
                      out_ref, False, order, kernel_ok)
 
@@ -248,6 +252,25 @@ class GrowthPlan:
         self.created = created or {}
         self._executors: Dict[Any, Any] = {}
         self._spec_cache: Dict[Tuple[int, int], Any] = {}
+        self._route_reported = False
+
+    def kernel_groups(self) -> Tuple[int, int]:
+        """(groups that take the fused Pallas route, all groups)."""
+        return sum(g.kernel_ok for g in self.groups), len(self.groups)
+
+    def _report_route(self) -> None:
+        """Say once per plan which groups a kernel-enabled apply runs on the
+        einsum contractions instead of the fused kernels."""
+        if self._route_reported:
+            return
+        self._route_reported = True
+        k, n = self.kernel_groups()
+        einsum = [f"{g.kind or 'top'}/{g.paths[0]}{list(g.shape)}"
+                  + (f"x{len(g.paths)}" if len(g.paths) > 1 else "")
+                  for g in self.groups if not g.kernel_ok]
+        print(f"[plan] {self.cfg1.name} -> {self.cfg2.name}: {k}/{n} groups "
+              f"on the fused kernels; einsum route: {', '.join(einsum)}",
+              file=sys.stderr)
 
     # -- resolution cache (one resolve per distinct (expr, role) per apply) --
     def _expander_table(self, width) -> Dict[ExprRef, jax.Array]:
@@ -342,6 +365,8 @@ class GrowthPlan:
         """
         if use_kernel is None:
             use_kernel = jax.default_backend() == "tpu"
+        if use_kernel:
+            self._report_route()
         group_sh = (self._group_shardings(mesh)
                     if mesh is not None and constrain_groups else None)
         width = ligo["width"]
@@ -374,6 +399,8 @@ class GrowthPlan:
             if use_kernel and g.kernel_ok and w_g is not None:
                 out = self._run_group_fused(g, X, E_in, E_out, w_g, mesh=mesh)
             else:
+                if use_kernel:
+                    LAUNCH_COUNTS.inc("einsum")
                 out = self._run_group(g, X, E_in, E_out, w_g)
             if g.bcast:
                 # Expert replication: (G, L2, a, b) → (G, L2, E, a, b).
@@ -518,11 +545,15 @@ class GrowthPlan:
 # Plan construction (memoised on config pair + tree signature)
 # ---------------------------------------------------------------------------
 def _tree_signature(small) -> Tuple:
+    """(path, shape, itemsize) of every leaf — the itemsize sizes the fused
+    kernels' VMEM blocks."""
+    def leaf(p, v):
+        return (p, tuple(v.shape), jnp.dtype(v.dtype).itemsize)
     layers = tuple(sorted(
-        (kind, tuple(sorted((p, tuple(v.shape))
+        (kind, tuple(sorted(leaf(p, v)
                             for p, v in _flatten(stack).items())))
         for kind, stack in small["layers"].items()))
-    top = tuple(sorted((p, tuple(v.shape)) for p, v in _flatten(
+    top = tuple(sorted(leaf(p, v) for p, v in _flatten(
         {k: v for k, v in small.items() if k != "layers"}).items()))
     return (layers, top)
 
@@ -551,21 +582,23 @@ def _build_plan(cfg1: ModelConfig, cfg2: ModelConfig, sig) -> GrowthPlan:
         tgt_kind = kmap.get(kind, kind)
         L2 = c2.get(tgt_kind, 0)
         buckets: Dict[Tuple, list] = {}
-        for path, shape in leaves:
+        for path, shape, isz in leaves:
             in_e, out_e = lspec[path]
             vec = len(shape) == (2 if stacked else 1)
             dst = renames.get(path, path)
             bc = bcast_map.get(dst, 0)
             key = (shape, _expr_key(in_e) if not vec else None,
                    _expr_key(out_e), vec, bc)
-            buckets.setdefault(key, []).append((path, dst, in_e, out_e))
+            buckets.setdefault(key, []).append(
+                (path, dst, in_e, out_e, isz))
         for (shape, _ik, _ok, vec, bc), members in sorted(buckets.items(),
                                                           key=str):
-            paths = tuple(p for p, _, _, _ in members)
-            dsts = tuple(d for _, d, _, _ in members)
+            paths = tuple(m[0] for m in members)
+            dsts = tuple(m[1] for m in members)
             in_e, out_e = members[0][2], members[0][3]
             g = _plan_group(kind, stacked, paths, shape,
-                            None if vec else in_e, out_e, vec, L2, cfg1, cfg2)
+                            None if vec else in_e, out_e, vec, L2, cfg1, cfg2,
+                            max(m[4] for m in members))
             if hop is not None:
                 g = dataclasses.replace(
                     g, out_kind=tgt_kind if tgt_kind != kind else "",
@@ -577,17 +610,18 @@ def _build_plan(cfg1: ModelConfig, cfg2: ModelConfig, sig) -> GrowthPlan:
 
     tspec = S.top_spec()
     buckets = {}
-    for path, shape in top_sig:
+    for path, shape, isz in top_sig:
         in_e, out_e = tspec[path]
         vec = len(shape) == 1
         key = (shape, _expr_key(in_e) if not vec else None,
                _expr_key(out_e), vec)
-        buckets.setdefault(key, []).append((path, in_e, out_e))
+        buckets.setdefault(key, []).append((path, in_e, out_e, isz))
     for (shape, _ik, _ok, vec), members in sorted(buckets.items(), key=str):
-        paths = tuple(p for p, _, _ in members)
+        paths = tuple(m[0] for m in members)
         in_e, out_e = members[0][1], members[0][2]
         g = _plan_group("", False, paths, shape, None if vec else in_e,
-                        out_e, vec, 0, cfg1, cfg2)
+                        out_e, vec, 0, cfg1, cfg2,
+                        max(m[3] for m in members))
         if not vec:
             register(in_e, "in")
         register(out_e, "out")

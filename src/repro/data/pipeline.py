@@ -65,9 +65,14 @@ class Prefetcher:
 
         def worker():
             for item in it:
+                while not self._stop.is_set():
+                    try:
+                        self.q.put(item, timeout=0.05)
+                        break
+                    except queue.Full:
+                        continue
                 if self._stop.is_set():
                     return
-                self.q.put(item)
 
         self.t = threading.Thread(target=worker, daemon=True)
         self.t.start()
@@ -79,9 +84,12 @@ class Prefetcher:
         return self.q.get()
 
     def close(self):
+        """Stop the worker and wait for it: a worker still producing
+        batches while the interpreter shuts down crashes the process."""
         self._stop.set()
         try:
             while True:
                 self.q.get_nowait()
         except queue.Empty:
             pass
+        self.t.join(timeout=30)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array, *,
                    mesh: Mesh, axis: str = "pod", microbatches: int = 8
@@ -77,8 +75,8 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array, *,
     other_axes = tuple(a for a in mesh.axis_names if a != axis)
     in_specs = (P(axis), P())
     out_specs = P()
-    fn = compat.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
+                    out_specs=out_specs, check_vma=False)
     outs = fn(stage_params, x_mb)
     return outs.reshape((B,) + x.shape[1:])
 

@@ -17,14 +17,20 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def current_mesh() -> Optional[Mesh]:
+    """The ambient mesh installed by ``jax.set_mesh``, or None.
+
+    Outside ``jit`` this is the concrete ``Mesh``: shardings built on it
+    carry devices and memory kinds, which ``jax.jit(in_shardings=...)`` and
+    ``device_put`` need. While tracing only the abstract mesh is visible,
+    which is all ``with_sharding_constraint`` and ``shard_map`` need.
+    """
     try:
-        return compat.get_mesh()
-    except Exception:
-        return None
+        m = jax.sharding.get_mesh()
+    except ValueError:                  # inside jit
+        m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def physical_spec(spec: P, mesh) -> P:

@@ -42,8 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _pick_tile(d: int, cap: int) -> int:
     """One full block for small dims (no padding), cap-tiles above."""
@@ -63,40 +61,86 @@ def fused_tiles(i: int, b: int, *, ti: int = 128, tb: int = 128):
     return _pick_tile(i, ti), _pick_tile(b, tb)
 
 
-def fused_vmem_bytes(L1: int, i: int, a: int, b: int) -> int:
-    """Worst-case VMEM residency (bytes) of the fwd/bwd kernels for one grid
-    step: resident operand blocks + f32 scratch accumulators. The bwd kernel
-    dominates — it holds the padded expander B, the full (I, A) dB
-    accumulator and the (L1, A, TB) dW accumulator in VMEM."""
+# Scoped-VMEM limit both fused kernels ask Mosaic for (v5e's default scoped
+# limit is 16 MiB of its 128 MiB). ``fused_eligible`` admits exactly the
+# shapes whose modelled residency fits under it.
+VMEM_LIMIT_BYTES = 16 * 2 ** 20
+
+
+def _vmem_tile_bytes(shape, itemsize: int) -> int:
+    """VMEM bytes of one buffer: Mosaic pads the last two dims to whole
+    (sublane, 128-lane) tiles — 8 rows of 32-bit, 16 of 16-bit values."""
+    *lead, rows, cols = shape
+    sub = 8 * (4 // itemsize)
+    n = 1
+    for d in lead:
+        n *= d
+    return n * (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
+
+
+def fused_vmem_bytes(L1: int, L2: int, i: int, a: int, b: int, *,
+                     G: int = 2, itemsize: int = 4) -> int:
+    """VMEM (bytes) the fwd and bwd kernels ask Mosaic for, the larger of
+    the two, with every buffer padded to whole tiles: the operand and output
+    blocks (two buffers each, one where the block index is grid-invariant),
+    the f32 scratch accumulators and the large f32 values of the kernel
+    body. ``G`` is the grid's folded leaf × expert count (``G == 1`` makes
+    the ``w`` block, and with one b tile the ``W`` block, grid-invariant)
+    and ``itemsize`` the parameter dtype's; ``w`` and every accumulator are
+    float32.
+
+    On v5e the forward count matches the compiler's scoped allocation for
+    plain (E = 1) leaves. The backward count is an upper bound: XLA may
+    place the small ``dB`` partial output in VMEM itself, and then the
+    kernel needs no buffers for it. The bwd kernel dominates — it holds the
+    padded expander ``B``, the (I, A) ``dB`` accumulator and its partial
+    output, and the (L1, A, TB) ``dW`` accumulator."""
     ti, tb = fused_tiles(i, b)
     i_pad = -(-i // ti) * ti
-    fwd = (i_pad * a + L1 * a * tb + a * tb + ti * tb) * 4
-    bwd = (2 * i_pad * a + i * a + 3 * L1 * a * tb + 2 * a * tb
-           + ti * tb) * 4
+    n_b = -(-b // tb)
+    t = _vmem_tile_bytes
+    # a block whose index never changes over the grid gets one buffer,
+    # every other block two (the pipeline prefetches the next one)
+    w_bufs = 1 if G == 1 else 2
+    W_bufs = 1 if G == 1 and n_b == 1 else 2
+    w_blk, B_blk = t((L2, L1), 4), t((i_pad, a), itemsize)
+    W_blk, tile = L1 * t((a, tb), itemsize), t((ti, tb), itemsize)
+    acc = t((a, tb), 4)
+    fwd = w_bufs * w_blk + B_blk + W_bufs * W_blk + 2 * tile + acc
+    bwd = (w_bufs * w_blk + B_blk + W_bufs * W_blk + 2 * tile        # ins
+           + 2 * (W_blk + t((i, a), 4) + t((L2, L1), 4))            # outs
+           + 2 * acc + L1 * acc + t((i_pad, a), 4)                  # scratch
+           # f32 values of the body: the (L1, A·TB) dW update, a copy of
+           # T, the upcast dP tile
+           + L1 * acc + acc + t((ti, tb), 4))
     return max(fwd, bwd)
 
 
 def fused_eligible(L1: int, L2: int, E: int, i: int, a: int, b: int, *,
-                   vmem_budget: int = 10 * 2 ** 20) -> bool:
+                   G: int = 2, itemsize: int = 4) -> bool:
     """Can (L1[, E], a, b) stacked leaves run on the fused fwd+bwd kernels?
 
     Universal in shape — G and E fold into the grid, ragged dims are handled
     by block padding / pre-padded zeros — so the only rejections are
-    degenerate dims and shapes whose resident VMEM state would overflow.
+    degenerate dims and shapes whose VMEM residency exceeds the limit the
+    kernels compile under. ``G`` (leaves in the group) and ``itemsize``
+    (parameter dtype) default to the conservative side.
     """
     if min(L1, L2, E, i, a, b) < 1:
         return False
-    return fused_vmem_bytes(L1, i, a, b) <= vmem_budget
+    return fused_vmem_bytes(L1, L2, i, a, b, G=G * E,
+                            itemsize=itemsize) <= VMEM_LIMIT_BYTES
 
 
 def _kernel(w_ref, b_ref, W_ref, out_ref, bl_ref, *, L1: int, ti: int):
+    k = pl.program_id(2)
     i = pl.program_id(3)
 
     @pl.when(i == 0)
     def _blend():
         # blend the small stack slab for this (g, l2): (A, TB) — once per
         # (b, n, l2), VPU work overlapped with the MXU contraction below
-        w_row = w_ref[0, 0]                              # (L1,)
+        w_row = w_ref[0, k]                              # (L1,)
         slab = W_ref[0, :, 0]                            # (L1, A, TB)
         bl_ref[...] = jax.lax.dot_general(
             w_row[None, :], slab.reshape(L1, -1),
@@ -139,7 +183,7 @@ def ligo_blend_expand_grouped(w: jax.Array, B: jax.Array, W: jax.Array, *,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, L1), lambda b, n, k, i: (n // E, k, 0)),
+            pl.BlockSpec((1, L2, L1), lambda b, n, k, i: (n // E, 0, 0)),
             pl.BlockSpec((n_i * ti, A), lambda b, n, k, i: (0, 0)),
             pl.BlockSpec((1, L1, 1, A, tb),
                          lambda b, n, k, i: (n // E, 0, n % E, 0, b)),
@@ -148,9 +192,10 @@ def ligo_blend_expand_grouped(w: jax.Array, B: jax.Array, W: jax.Array, *,
                                lambda b, n, k, i: (n // E, k, n % E, i, b)),
         out_shape=jax.ShapeDtypeStruct((G, L2, E, I, Bd), B.dtype),
         scratch_shapes=[pltpu.VMEM((A, tb), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(w.astype(jnp.float32), B_pad, W)
 

@@ -53,8 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-from repro.kernels.ligo_expand import _pad_rows, fused_tiles
+from repro.kernels.ligo_expand import (VMEM_LIMIT_BYTES, _pad_rows,
+                                       fused_tiles)
 
 
 def _mask_tail(x, axis: int, valid: int):
@@ -79,7 +79,7 @@ def _bwd_kernel(w_ref, b_ref, W_ref, dP_ref, dW_ref, dBp_ref, dwp_ref,
             slab = _mask_tail(slab, 2, b_dim - b * tb)
         return slab
 
-    w_row = w_ref[0, 0].astype(jnp.float32)              # (L1,)
+    w_row = w_ref[0, k].astype(jnp.float32)              # (L1,)
 
     @pl.when((n == 0) & (k == 0) & (i == 0))
     def _zero_db():
@@ -123,7 +123,7 @@ def _bwd_kernel(w_ref, b_ref, W_ref, dP_ref, dW_ref, dBp_ref, dwp_ref,
             w_row[:, None], T.reshape(1, -1),
             preferred_element_type=jnp.float32).reshape(dW_acc.shape)
         # dw[g, k, :] partial for this b tile: ⟨T, W[l]⟩ — (L1,)
-        dwp_ref[0, 0, 0] = jax.lax.dot_general(
+        dwp_ref[0, 0, k] = jax.lax.dot_general(
             masked_slab().reshape(L1, -1), T.reshape(-1),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -165,7 +165,7 @@ def ligo_blend_expand_bwd_fused(w: jax.Array, B: jax.Array, W: jax.Array,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, L1), lambda b, n, k, i: (n // E, k, 0)),
+            pl.BlockSpec((1, L2, L1), lambda b, n, k, i: (n // E, 0, 0)),
             pl.BlockSpec((i_pad, A), lambda b, n, k, i: (0, 0)),
             pl.BlockSpec((1, L1, 1, A, tb),
                          lambda b, n, k, i: (n // E, 0, n % E, 0, b)),
@@ -176,7 +176,7 @@ def ligo_blend_expand_bwd_fused(w: jax.Array, B: jax.Array, W: jax.Array,
             pl.BlockSpec((1, L1, 1, A, tb),
                          lambda b, n, k, i: (n // E, 0, n % E, 0, b)),
             pl.BlockSpec((1, I, A), lambda b, n, k, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, 1, L1), lambda b, n, k, i: (b, n, k, 0)),
+            pl.BlockSpec((1, 1, L2, L1), lambda b, n, k, i: (b, n, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((G, L1, E, A, Bd), W.dtype),
@@ -189,9 +189,10 @@ def ligo_blend_expand_bwd_fused(w: jax.Array, B: jax.Array, W: jax.Array,
             pltpu.VMEM((L1, A, tb), jnp.float32),    # dW accumulator
             pltpu.VMEM((i_pad, A), jnp.float32),     # dB accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(w.astype(jnp.float32), B_pad, W, dP)
 

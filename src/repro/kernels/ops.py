@@ -147,8 +147,6 @@ def ligo_blend_expand_grouped_sharded(w, B, W, mesh, *, use_kernel=None):
     if mesh is None:
         return ligo_blend_expand_grouped_vjp(w, B, W, use_kernel=use_kernel)
     from jax.sharding import PartitionSpec as P
-
-    from repro import compat
     from repro.distributed.sharding import divisible_axes
 
     G, Bd = W.shape[0], W.shape[-1]
@@ -162,7 +160,7 @@ def ligo_blend_expand_grouped_sharded(w, B, W, mesh, *, use_kernel=None):
         spec_W = spec_out = P(axes_g, None, None, None, None)
     else:
         return ligo_blend_expand_grouped_vjp(w, B, W, use_kernel=use_kernel)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         functools.partial(ligo_blend_expand_grouped_vjp,
                           use_kernel=use_kernel),
         mesh=mesh, in_specs=(spec_w, P(), spec_W), out_specs=spec_out,

@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import (ASSIGNED, SHAPES, TrainConfig, enumerate_cells,
                            get_config)
 from repro.distributed.sharding import (batch_specs, named_shardings,
@@ -160,7 +159,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
     t0 = time.time()
     fn, args, in_sh, out_sh, meta = build_cell(cfg, shape, mesh,
                                                tuning=tuning)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0

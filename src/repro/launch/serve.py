@@ -26,7 +26,8 @@ double-buffered in the background, live sessions' KV caches migrate
 (in-place growth when the operator is lossless, re-prefill otherwise), and
 the buffers swap atomically between decode steps. A failed hop (inject one
 with ``--fail-at-hop grow|cache-grow|swap|hang``) rolls back and retries
-with backoff; in-flight requests never drop either way.
+with backoff; in-flight requests never drop either way. A hop that gives up
+after its retries, or a dropped request, makes the driver exit non-zero.
 
 On the production mesh, params are FSDP+TP sharded and the KV cache is
 sequence- or head-sharded per repro.distributed.sharding.state_pspecs; on CPU
@@ -43,8 +44,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, grow_target, moe_target, smoke_config
-from repro import compat, obs
+from repro import obs
 from repro.data import gen_tokens
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import decode_step, init_params, prefill
 
@@ -153,7 +155,10 @@ def _restore_ckpt(ckpt_dir: str, cfg, mesh):
 
 
 def _serve_live(args, cfg, params, mesh):
-    """Engine-backed serving with a mid-serve hop (``--live-grow-at``)."""
+    """Engine-backed serving with a mid-serve hop (``--live-grow-at``).
+
+    Returns the run's outcome: request counts, whether the hop completed
+    and after how many attempts, the served architecture and token count."""
     from repro.core import compose_chain, init_ligo_params
     from repro.serving import HopController, ServingEngine
     if cfg.modality != "text":
@@ -276,9 +281,17 @@ def _serve_live(args, cfg, params, mesh):
         print(f"[paged] peak {a.peak_blocks} blocks | "
               f"{a.bytes_per_slot(block_bytes) / 1024:.1f} KiB/slot vs "
               f"{dense_bytes / 1024:.1f} KiB/slot dense")
+    return {"requests": n_req, "done": c["done"], "dropped": c["dropped"],
+            "rejected": c["rejected"], "hop_completed": hop.completed,
+            "hop_attempts": hop.attempts, "cache_path": hop.cache_path,
+            "arch": (cfg2 if hop.completed else cfg).name, "tokens": total,
+            "wall_s": wall}
 
 
-def main():
+def main(argv=None):
+    """Parse ``argv`` (default ``sys.argv``) and serve. Returns the live
+    path's outcome (see :func:`_serve_live`), or None for the batch path;
+    exits non-zero when the live hop gave up or a request was dropped."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -398,7 +411,8 @@ def main():
                          "params_pspecs, expanders replicated, the fused "
                          "kernel per-shard under shard_map — so 8B+ targets "
                          "grow in place on the production mesh")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.metrics_port is not None:
         srv = obs.serve_metrics(args.metrics_port)
@@ -412,7 +426,7 @@ def main():
         obs.attach_jsonl(args.obs_log)
     try:
         with obs.profile(args.obs_profile):
-            _serve(args)
+            res = _serve(args)
     finally:
         if args.obs_report:
             print(obs.report())
@@ -432,6 +446,12 @@ def main():
         if args.obs_log:
             path = obs.close_jsonl()
             print(f"[obs] structured log written to {path}")
+    if res is not None and not res["hop_completed"]:
+        raise SystemExit(f"[serve] the hop to {args.grow_to or '2x'} gave up "
+                         f"after {res['hop_attempts']} attempts")
+    if res is not None and res["dropped"]:
+        raise SystemExit(f"[serve] {res['dropped']} requests dropped")
+    return res
 
 
 def _serve(args):
@@ -443,14 +463,13 @@ def _serve(args):
     mesh = (make_host_mesh() if args.mesh == "host"
             else make_production_mesh(multi_pod=(args.mesh == "multi")))
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if args.ckpt:
             params = _restore_ckpt(args.ckpt, cfg, mesh)
         else:
             params = init_params(cfg, jax.random.PRNGKey(0))
         if args.live_grow_at is not None:
-            _serve_live(args, cfg, params, mesh)
-            return
+            return _serve_live(args, cfg, params, mesh)
         if args.grow_to:
             params, cfg = hot_grow(params, cfg, args.grow_to,
                                    smoke=args.smoke)

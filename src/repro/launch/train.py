@@ -35,11 +35,12 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import (TrainConfig, get_config, half_config, smoke_config)
-from repro import compat, obs
+from repro import obs
 from repro.core import grow
 from repro.data import GlobalBatchLoader
 from repro.distributed.sharding import named_shardings, params_pspecs
 from repro.distributed.supervisor import Supervisor
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import init_params
 from repro.optim import adamw_init
@@ -121,6 +122,7 @@ def main():
                          "format at GET /metrics on this port (0 binds an "
                          "ephemeral port; the bound port is printed)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.ledger and not (args.trajectory or args.autogrow):
         raise SystemExit("--ledger requires --trajectory/--autogrow: the "
@@ -209,7 +211,7 @@ def _train(args):
     dp_sz = mesh.shape.get("data", 1) * mesh.shape.get("pod", 1)
     act_spec = P("data", "model", None) if args.seq_shard else None
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # ---- source model ------------------------------------------------
         if args.grow_from:
             small_cfg = (half_config(cfg) if args.grow_from == "half"

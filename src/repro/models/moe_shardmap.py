@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.distributed.sharding import current_mesh
 
 
@@ -153,7 +152,7 @@ def apply_moe_shardmap(p, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
     espec = P("data", None, None) if sharded_w else P()
     in_specs = (batch_spec, P(), espec, espec) + ((espec,) if has_w3 else ())
     args = (x, p["router"], p["w1"], p["w2"]) + ((p["w3"],) if has_w3 else ())
-    out, aux = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=(batch_spec, P()),
-                             check_vma=False)(*args)
+    out, aux = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                          out_specs=(batch_spec, P()),
+                          check_vma=False)(*args)
     return out, aux

@@ -21,6 +21,12 @@ includes elementwise work) dominates the dot-only count. Per-device
 numbers are scaled by ``n_devices`` for SPMD programs so they compare
 against the global modelled count.
 
+A program that cannot be lowered or compiled, or whose backend exposes no
+cost analysis, is not measured: the failure is counted per program in the
+``ledger.measure.failures`` counter group and printed to stderr, and the
+caller carries on unmeasured. Entry points that must not run unmeasured
+check that group.
+
 Every measurement lands in :data:`MEASUREMENTS`, publishes the
 ``ledger.flops.modelled`` / ``ledger.flops.measured`` gauges plus the
 ``ledger.flops.ratio`` reconciliation gauge (measured/modelled), and
@@ -38,6 +44,7 @@ original run's measured column exactly.
 """
 from __future__ import annotations
 
+import sys
 import threading
 from typing import Any, Dict, Optional
 
@@ -63,6 +70,15 @@ def measurement(name: str) -> Optional[Dict[str, Any]]:
         return MEASUREMENTS.get(name)
 
 
+def _failed(name: str, what: str, err: Exception) -> None:
+    """Count and report a program the pass could not measure."""
+    _metrics.counter_group("ledger.measure.failures").inc(name)
+    _trace.event("ledger.measure_failed", program=name, stage=what,
+                 error=f"{type(err).__name__}: {err}")
+    print(f"[obs] measured-cost pass: {what} of {name} failed: "
+          f"{type(err).__name__}: {err}", file=sys.stderr)
+
+
 def measure_compiled(name: str, compiled, *,
                      modelled_flops: Optional[float] = None,
                      n_devices: int = 1,
@@ -74,14 +90,12 @@ def measure_compiled(name: str, compiled, *,
     divides by it. ``modelled_flops`` is the roofline prediction for one
     call (same units), enabling the reconciliation ratio. Returns the
     measurement dict, or ``None`` when the backend exposes no cost
-    analysis (measurement is best-effort by design).
+    analysis; that failure is counted (see the module docstring).
     """
     try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-            cost = cost[0] if cost else {}
-        cost = dict(cost or {})
-    except Exception:
+        cost = dict(compiled.cost_analysis() or {})
+    except Exception as e:  # backends without a cost model raise here
+        _failed(name, "cost analysis", e)
         return None
     try:
         from repro.roofline import collect_hlo_stats
@@ -141,12 +155,13 @@ def measure_jitted(name: str, jitted, *args,
 
     ``args`` may mix concrete arrays and ``jax.ShapeDtypeStruct`` trees —
     lowering never executes the program (donated buffers stay live).
-    Swallows lowering/compile failures and returns ``None``: the caller's
-    job (training) must not die because a backend cannot be measured.
+    A lowering or compile failure is counted and reported, and returns
+    ``None``: the caller's job (training) goes on unmeasured.
     """
     try:
         compiled = jitted.lower(*args).compile()
-    except Exception:
+    except Exception as e:  # any compiler error; counted, never hidden
+        _failed(name, "compile", e)
         return None
     return measure_compiled(name, compiled, modelled_flops=modelled_flops,
                             n_devices=n_devices,

@@ -25,7 +25,7 @@ import os
 from typing import Dict, List, Optional
 
 from repro.configs import SHAPES, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, peaks
 
 ART = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                    "artifacts", "dryrun")
@@ -56,14 +56,15 @@ def model_flops(arch: str, shape_name: str) -> float:
 def analyse_cell(rec: Dict) -> Dict:
     chips = rec["n_devices"]
     hlo = rec["hlo"]
-    compute = hlo["dot_flops"] / PEAK_FLOPS_BF16
-    memory = hlo["hbm_bytes"] / HBM_BW
-    collective = hlo["collective_wire_bytes"] / ICI_BW
+    pk = peaks(PRODUCTION_DEVICE_KIND)
+    compute = hlo["dot_flops"] / pk["flops_bf16"]
+    memory = hlo["hbm_bytes"] / pk["hbm_bw"]
+    collective = hlo["collective_wire_bytes"] / pk["ici_bw"]
     terms = {"compute": compute, "memory": memory, "collective": collective}
     bottleneck = max(terms, key=terms.get)
     step_time = max(terms.values())
     mf = model_flops(rec["arch"], rec["shape"])
-    useful = mf / chips / PEAK_FLOPS_BF16
+    useful = mf / chips / pk["flops_bf16"]
     fraction = useful / step_time if step_time > 0 else 0.0
     hlo_flops_global = hlo["dot_flops"] * chips
     advice = {

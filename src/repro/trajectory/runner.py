@@ -53,7 +53,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat, obs
+from repro import obs
 from repro.autogrow import Telemetry, make_policy, probe_methods
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import TrainConfig
@@ -82,6 +82,9 @@ class TrajectoryRunner:
         # this phase step (threaded into train_ligo; tests + CI smoke)
         self.ligo_fail_at = ligo_fail_at
         self.decisions: List[Dict[str, Any]] = []
+        # stage index -> the (composed) operator that grew into it in this
+        # process; callers inspect or re-apply the learned operators
+        self.operators: Dict[int, Dict] = {}
         self._tele_restore: Optional[Dict] = None
         # the compute ledger (explicit, or whatever --ledger attached):
         # its cursor rides every checkpoint meta like the telemetry ring,
@@ -308,6 +311,7 @@ class TrajectoryRunner:
             specs.append(gs)
         composed = (ops_chain[0] if len(ops_chain) == 1
                     else compose_chain(ops_chain, cfg_chain))
+        self.operators[last] = composed
         params = apply_ligo(composed, params, cfg_chain[0], cfg_chain[-1],
                             mesh=self.mesh)
         carry = all(gs.grow_optimizer for gs in specs)
@@ -334,7 +338,7 @@ class TrajectoryRunner:
         """Drive the trajectory to completion (or to ``max_steps`` global
         train steps). Returns the final state + bookkeeping; ``status`` is
         ``"done"`` or ``"paused"``."""
-        ctx = (compat.set_mesh(self.mesh) if self.mesh is not None
+        ctx = (jax.set_mesh(self.mesh) if self.mesh is not None
                else nullcontext())
         with ctx:
             return self._run(max_steps, on_metrics)
@@ -379,7 +383,8 @@ class TrajectoryRunner:
                     "stage_step": k, "global_step": global_step,
                     "history": history, "status": status,
                     "resumed_at": self.resumed_at, "timings": timings,
-                    "decisions": self.decisions}
+                    "decisions": self.decisions,
+                    "operators": self.operators}
 
         while True:
             st = stages[stage]

@@ -267,17 +267,25 @@ def test_runner_auto_stage_ends_at_plateau_before_cap():
 def test_runner_auto_stage_kill_resume_same_decision():
     """Pause mid-auto-stage: the telemetry tail rides the checkpoint meta,
     so the resumed run fires the policy at the same step with the same
-    final state as the uninterrupted run."""
+    final state as the uninterrupted run.
+
+    The step at which the plateau fires depends on the random init, so the
+    pause point is taken from the uninterrupted run: one step before the
+    decision, which is inside the auto stage because the policy cannot fire
+    before its ``min_steps``."""
+    with tempfile.TemporaryDirectory() as d:
+        full = TrajectoryRunner(AUTO_TRAJ, ckpt_dir=d, verbose=False).run()
+    stage0_steps = AUTO_TRAJ.stages[0].steps
+    pause_at = full["decisions"][-1]["global_step"] - 1
+    assert pause_at > stage0_steps, "decision fired before the auto stage"
     with tempfile.TemporaryDirectory() as d:
         r1 = TrajectoryRunner(AUTO_TRAJ, ckpt_dir=d,
-                              verbose=False).run(max_steps=7)
+                              verbose=False).run(max_steps=pause_at)
         assert r1["status"] == "paused"
         meta = CheckpointManager(d).latest_meta()
         assert meta["stage"] == 1 and "autogrow" in meta
         assert meta["autogrow"]["ring"], "telemetry tail not checkpointed"
         r2 = TrajectoryRunner(AUTO_TRAJ, ckpt_dir=d, verbose=False).run()
-    with tempfile.TemporaryDirectory() as d:
-        full = TrajectoryRunner(AUTO_TRAJ, ckpt_dir=d, verbose=False).run()
     assert r2["status"] == full["status"] == "done"
     assert r2["decisions"][-1]["stage_step"] == \
         full["decisions"][-1]["stage_step"]

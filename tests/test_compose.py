@@ -179,7 +179,7 @@ def test_compose_property_random_triples():
         c3 = c2.scaled(name="hc3", n_layers=l1 + d1 + d2, d_model=h3 * dh,
                        n_heads=h3, n_kv_heads=h3,
                        d_ff=(f1 + g1 + g2 + 1) * h3 * dh)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             f64 = lambda t: jax.tree.map(  # noqa: E731
                 lambda x: jnp.asarray(np.asarray(x), jnp.float64), t)
             sp = f64(init_params(c1, jax.random.PRNGKey(0)))

@@ -13,7 +13,6 @@ def test_sharded_growth_end_to_end(subproc):
     phase (jitted scan differentiating through the sharded plan) runs."""
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.configs.paper_models import BERT_SMALL
 from repro.core import apply_ligo, init_ligo_params, plan_for, train_ligo
 from repro.launch.mesh import make_mesh
@@ -29,7 +28,7 @@ sp = init_params(c1, jax.random.PRNGKey(0))
 lg = init_ligo_params(jax.random.PRNGKey(1), c1, c2)
 mesh = make_mesh((2, 4), ("data", "model"))
 legacy = apply_ligo(lg, sp, c1, c2, engine="legacy")
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     big = apply_ligo(lg, sp, c1, c2)          # ambient mesh -> sharded plan
 for a, b in zip(jax.tree.leaves(legacy), jax.tree.leaves(big)):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -39,7 +38,7 @@ assert any(not l.sharding.is_fully_replicated for l in jax.tree.leaves(big))
 def batches():
     while True:
         yield dummy_batch(c1, 2, 16, "train")
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     _, losses = train_ligo(lg, sp, c1, c2, batches(), steps=4, scan_chunk=2)
 assert len(losses) == 4 and all(np.isfinite(losses)), losses
 print("SHARDED_GROW_OK")
@@ -50,7 +49,7 @@ print("SHARDED_GROW_OK")
 def test_pjit_train_step_matches_unsharded(subproc):
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.paper_models import GPT2_BASE
 from repro.configs.base import TrainConfig
@@ -69,12 +68,12 @@ step1 = jax.jit(make_train_step(cfg, tcfg))
 p1, o1, m1 = step1(params, opt, batch, jnp.asarray(0))
 
 # 2x4 mesh pjit
-mesh = compat.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 pspecs = params_pspecs(params, model_size=4, dp_size=2)
 psh = named_shardings(pspecs, mesh)
 osh = type(opt)(m=psh, v=psh, count=NamedSharding(mesh, P()))
 bsh = named_shardings(batch_specs(batch, dp_size=2), mesh)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     step2 = jax.jit(make_train_step(cfg, tcfg),
                     in_shardings=(psh, osh, bsh, NamedSharding(mesh, P())))
     p2, o2, m2 = step2(params, opt, batch, jnp.asarray(0))
@@ -89,7 +88,7 @@ print("PJIT_OK")
 def test_sequence_parallel_residual_matches(subproc):
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.paper_models import GPT2_BASE
 from repro.data import batch_for_step
@@ -99,8 +98,8 @@ cfg = GPT2_BASE.scaled(name="t", n_layers=2, d_model=64, n_heads=4, n_kv_heads=4
 params = init_params(cfg, jax.random.PRNGKey(0))
 batch = {k: jnp.asarray(v) for k, v in batch_for_step(cfg, 0, 4, 32, seed=0).items()}
 l_plain, _ = loss_fn(params, cfg, batch)
-mesh = compat.make_mesh((2, 4), ("data", "model"))
-with compat.set_mesh(mesh):
+mesh = make_mesh((2, 4), ("data", "model"))
+with jax.set_mesh(mesh):
     l_sp = jax.jit(lambda p, b: loss_fn(p, cfg, b,
                    act_spec=P("data", "model", None))[0])(params, batch)
 np.testing.assert_allclose(float(l_plain), float(l_sp), rtol=1e-5)
@@ -112,9 +111,9 @@ print("SP_OK")
 def test_pipeline_parallel_equals_sequential(subproc):
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.distributed.pipeline import pipeline_apply, bubble_fraction
-mesh = compat.make_mesh((4,), ("pod",))
+mesh = make_mesh((4,), ("pod",))
 S, M, B, D = 4, 8, 16, 32
 rng = np.random.RandomState(0)
 stage_params = {"w": jnp.asarray(rng.randn(S, D, D) * 0.2, jnp.float32),
@@ -128,7 +127,7 @@ ref = x
 for s in range(S):
     ref = stage_fn(jax.tree.map(lambda a: a[s], stage_params), ref)
 
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     out = pipeline_apply(stage_fn, stage_params, x, mesh=mesh, axis="pod",
                          microbatches=M)
 np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
@@ -141,10 +140,10 @@ print("PIPE_OK")
 def test_compressed_psum_shard_map(subproc):
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.optim.compression import compressed_psum
-mesh = compat.make_mesh((4,), ("pod",))
+mesh = make_mesh((4,), ("pod",))
 rng = np.random.RandomState(0)
 g = jnp.asarray(rng.randn(4, 64), jnp.float32)     # per-pod gradients
 err0 = jnp.zeros((4, 64), jnp.float32)
@@ -153,9 +152,9 @@ def f(gi, ei):
     out, new_e = compressed_psum(gi[0], "pod", ei[0])
     return out[None], new_e[None]
 
-fn = compat.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                       out_specs=(P("pod"), P("pod")))
-with compat.set_mesh(mesh):
+fn = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                   out_specs=(P("pod"), P("pod")))
+with jax.set_mesh(mesh):
     out, err = fn(g, err0)
 mean_ref = np.asarray(g).mean(0)
 for i in range(4):
@@ -170,12 +169,12 @@ print("PSUM_OK")
 def test_global_batch_loader_sharded(subproc):
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.configs.paper_models import GPT2_BASE
 from repro.data import GlobalBatchLoader, batch_for_step
 from repro.data.pipeline import Prefetcher
 cfg = GPT2_BASE.scaled(vocab_size=64)
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 loader = GlobalBatchLoader(cfg, mesh, batch=8, seq=16, seed=0)
 b = loader.batch_at(0)
 host = batch_for_step(cfg, 0, 8, 16, seed=0)
@@ -195,7 +194,6 @@ def test_dryrun_machinery_small_mesh(subproc):
     """The dry-run builder end-to-end on a small mesh (fast smoke of (e))."""
     code = """
 import jax, jax.numpy as jnp
-from repro import compat
 from repro.configs import smoke_config, ASSIGNED, SHAPES
 from repro.launch.dryrun import build_cell
 from repro.launch.mesh import make_mesh
@@ -205,7 +203,7 @@ mesh = make_mesh((2, 4), ("data", "model"))
 cfg = smoke_config(ASSIGNED["llama3-8b"])
 shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64, global_batch=8)
 fn, args, in_sh, out_sh, meta = build_cell(cfg, shape, mesh)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh).lower(*args).compile()
 stats = collect_hlo_stats(compiled.as_text())
 assert stats["dot_flops"] > 0
@@ -219,33 +217,33 @@ def test_shardmap_moe_matches_dense(subproc):
     """Explicit-collective MoE == dense dispatch (both rep paths) + grads."""
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.configs import ASSIGNED, smoke_config
 from repro.models.moe import apply_moe, init_moe
 from repro.models.moe_shardmap import apply_moe_shardmap, moe_shardmap_available
 rng = np.random.RandomState(0)
 # rep=1 (E=4 experts on data=2)
-mesh = compat.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = smoke_config(ASSIGNED["qwen3-moe-30b-a3b"]).scaled(capacity_factor=8.0)
 p = init_moe(jax.random.PRNGKey(0), cfg)
 x = jnp.asarray(rng.randn(4, 8, cfg.d_model), jnp.float32) * 0.3
 ref, _ = apply_moe(p, x, cfg)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     assert moe_shardmap_available(cfg)
     out, _ = jax.jit(lambda pp, xx: apply_moe_shardmap(pp, xx, cfg))(p, x)
 np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 # rep=2 virtual replication (E=2 on data=4)
 cfg2 = smoke_config(ASSIGNED["mixtral-8x7b"]).scaled(
     n_experts=2, experts_top_k=1, capacity_factor=8.0)
-mesh2 = compat.make_mesh((4, 2), ("data", "model"))
+mesh2 = make_mesh((4, 2), ("data", "model"))
 p2 = init_moe(jax.random.PRNGKey(1), cfg2)
 x2 = jnp.asarray(rng.randn(4, 8, cfg2.d_model), jnp.float32) * 0.3
 ref2, _ = apply_moe(p2, x2, cfg2)
-with compat.set_mesh(mesh2):
+with jax.set_mesh(mesh2):
     out2, _ = jax.jit(lambda pp, xx: apply_moe_shardmap(pp, xx, cfg2))(p2, x2)
 np.testing.assert_allclose(np.asarray(out2), np.asarray(ref2), atol=1e-5)
 # differentiable
-with compat.set_mesh(mesh2):
+with jax.set_mesh(mesh2):
     g = jax.grad(lambda pp: jnp.sum(apply_moe_shardmap(pp, x2, cfg2)[0] ** 2))(p2)
 assert all(bool(jnp.all(jnp.isfinite(l))) for l in jax.tree.leaves(g))
 print("SHARDMAP_MOE_OK")
@@ -258,17 +256,17 @@ def test_moe_block_dispatches_shardmap(subproc):
     inside the full model forward (same loss as dense)."""
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.configs import ASSIGNED, smoke_config
 from repro.models import init_params, loss_fn
 from repro.models.inputs import dummy_batch
-mesh = compat.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = smoke_config(ASSIGNED["qwen3-moe-30b-a3b"])
 params = init_params(cfg, jax.random.PRNGKey(0))
 batch = dummy_batch(cfg, 2, 16, "train")
 _, m_dense = loss_fn(params, cfg, batch)
 cfg_sm = cfg.scaled(moe_impl="shard_map")
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     _, m_sm = jax.jit(lambda p, b: loss_fn(p, cfg_sm, b))(params, batch)
 # CE must match exactly; the aux load-balance loss uses per-shard fractions
 # (standard local-dispatch semantics) and may differ slightly.

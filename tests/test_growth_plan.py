@@ -138,6 +138,9 @@ def test_one_kernel_launch_per_group():
     jax.eval_shape(lambda l: plan.apply(l, sp, use_kernel=True), lg)
     assert LAUNCH_COUNTS["fwd"] == len(eligible), \
         (dict(LAUNCH_COUNTS), len(eligible), n_leaves)
+    # every other group is counted on the einsum route, never silently
+    assert LAUNCH_COUNTS["einsum"] == len(plan.groups) - len(eligible)
+    assert plan.kernel_groups() == (len(eligible), len(plan.groups))
 
     LAUNCH_COUNTS.clear()
     jax.eval_shape(jax.grad(lambda l: _loss(l, lambda l: plan.apply(

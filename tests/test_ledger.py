@@ -312,6 +312,22 @@ def test_measured_vs_modelled_reconciles_within_2x(uninterrupted):
     assert meas["ligo_chunk[tr1]"]["trip_annotations"] >= 1
 
 
+def test_measure_failure_is_counted_not_hidden(capsys):
+    """A program the pass cannot compile is counted per program name and
+    reported, and the caller gets ``None`` back to run unmeasured."""
+    fails = obs.counter_group("ledger.measure.failures")
+    before = fails["broken[x]"]
+
+    def broken(x):
+        raise ValueError("cannot trace this")
+
+    assert costs.measure_jitted("broken[x]", jax.jit(broken),
+                                np.ones(2, np.float32)) is None
+    assert fails["broken[x]"] == before + 1
+    assert "broken[x] failed" in capsys.readouterr().err
+    assert costs.measurement("broken[x]") is None
+
+
 def test_kill_mid_stage_resumes_record_identical(tmp_path):
     """Acceptance: kill the 3-stage trajectory mid-stage (global step 8 =
     stage 1 step 3), resume, and the final ledger is record-for-record

@@ -109,12 +109,11 @@ def test_sharded_fused_path_matches_legacy(mesh_factory, small_params,
 def test_ambient_mesh_auto_pickup(mesh_factory, small_params):
     """apply_ligo with no mesh argument grows sharded under set_mesh — the
     plumbing the train/serve drivers rely on."""
-    from repro import compat
     mesh = mesh_factory((2, 4), ("data", "model"))
     op = _operator("ligo")
     plan = plan_for(CFG1, CFG2, small_params)
     want = plan.executor()(op, small_params)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = apply_ligo(op, small_params, CFG1, CFG2)
     assert_trees_close_normalized(got, want, rel=1e-6)
     assert any(not leaf.sharding.is_fully_replicated

@@ -320,3 +320,17 @@ def test_serve_live_grow_cli(monkeypatch, capsys):
     assert "hop complete" in out
     assert "0 dropped" in out
     assert "tok/s" in out and "p99" in out
+
+
+def test_serve_live_grow_cli_gives_up_nonzero(capsys):
+    """A hop that fails with no retries left makes the driver exit
+    non-zero, after every request was still served by the old model."""
+    from repro.launch import serve
+    with pytest.raises(SystemExit, match="gave up after 1 attempts"):
+        serve.main([
+            "--arch", "llama3-8b", "--smoke", "--live-grow-at", "2",
+            "--fail-at-hop", "grow", "--hop-retries", "0", "--hop-sync",
+            "--grow-to", "2x", "--batch", "2", "--prompt-len", "8",
+            "--gen", "6"])
+    out = capsys.readouterr().out
+    assert "FAILED (gave up)" in out and "0 dropped" in out

@@ -145,3 +145,33 @@ def test_training_converges_toward_process_entropy():
     assert losses[-1] < losses[0] - 1.5
     assert losses[-1] < np.log(128) * 0.6          # well below uniform
     assert losses[-1] > optimal_loss(128) * 0.5    # and sane
+
+
+def test_compile_cache_dir(subproc, tmp_path, monkeypatch):
+    """The entry points' compile cache: with JAX_COMPILATION_CACHE_DIR set
+    the programs land in that directory and the setting is left alone;
+    without it the cache is the fixed in-checkout directory."""
+    code = """
+import os, jax, jax.numpy as jnp
+from repro.launch import compile_cache
+from repro.launch.compile_cache import CACHE_DIR, use_compile_cache
+env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+got = use_compile_cache()
+if env:
+    assert got == env and jax.config.jax_compilation_cache_dir == env
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.jit(lambda x: jnp.sin(x) * 3)(jnp.ones(8)).block_until_ready()
+    assert os.listdir(env), "nothing cached in JAX_COMPILATION_CACHE_DIR"
+else:
+    src = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(compile_cache.__file__))))
+    root = os.path.dirname(src)
+    assert got == CACHE_DIR == os.path.join(root, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == CACHE_DIR
+print("CACHE_OK")
+"""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert "CACHE_OK" in subproc(code, n_devices=1)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert "CACHE_OK" in subproc(code, n_devices=1)
