@@ -251,13 +251,21 @@ def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
         # host-boundary timing: float(l) on the losses forces the sync, so
         # the span wall covers the whole compiled chunk, never intrudes on it
         with obs.span("ligo.chunk", start=done, n=n) as sp_chunk:
-            raw = [next(data_it) for _ in range(n)]
-            if ledger is not None and led_state["tokens"] is None:
-                _ledger_prepare(raw[0], n)
-            batches = _stack_batches(raw)
-            ligo, mom, chunk_losses = run_chunk(ligo, mom, small_params,
-                                                batches)
-            chunk_losses = [float(l) for l in chunk_losses]
+            with obs.span("ligo.batches", n=n):
+                raw = [next(data_it) for _ in range(n)]
+                if ledger is not None and led_state["tokens"] is None:
+                    _ledger_prepare(raw[0], n)
+                batches = _stack_batches(raw)
+            # trace, lower, compile or cache lookup, enqueue; ``traced``
+            # counts the chunk program's traces this launch made
+            with obs.span("ligo.launch", n=n) as sp_launch:
+                traced = TRACE_COUNTS["train_ligo"]
+                ligo, mom, chunk_losses = run_chunk(ligo, mom, small_params,
+                                                    batches)
+                sp_launch.attrs["traced"] = (TRACE_COUNTS["train_ligo"]
+                                             - traced)
+            with obs.span("ligo.sync", n=n):
+                chunk_losses = [float(l) for l in chunk_losses]
             losses.extend(chunk_losses)
         h_chunk.observe(sp_chunk.dur_ms or 0.0)
         if ledger is not None:
@@ -363,56 +371,58 @@ def grow(small_params, cfg1: ModelConfig, cfg2: ModelConfig, *,
     ``ligo_ledger``/``ligo_ledger_ctx`` give the phase's per-step records
     to the compute ledger the same way.
     """
-    key = key if key is not None else jax.random.PRNGKey(0)
-    info: Dict[str, Any] = {"method": method}
-    _validate_opt_state(opt_state, small_params)
-    if method == "random":
-        big = init_params(cfg2, key)
-        if opt_state is not None:
-            from repro.optim import adamw_init
-            info["opt_state"] = adamw_init(big)
-        return big, info
-    if method == "stackbert":
-        op = ops.stackbert_operator(cfg1, cfg2, key=key)
-    elif method == "interpolation":
-        op = ops.interpolation_operator(cfg1, cfg2, key=key)
-    elif method == "net2net":
-        op = ops.net2net_operator(key, cfg1, cfg2)
-    elif method == "bert2bert":
-        op = ops.bert2bert_operator(key, cfg1, cfg2)
-    elif method == "lemon":
-        op = ops.lemon_operator(cfg1, cfg2)
-    elif method == "upcycle":
-        from repro.core.upcycle import upcycle_operator
-        op = upcycle_operator(cfg1, cfg2)
-    elif method == "gqa_merge":
-        op = ops.gqa_merge_operator(cfg1, cfg2)
-    elif method == "ligo":
-        op = init_ligo_params(key, cfg1, cfg2, depth_init=depth_init)
-        if ligo_steps and data_it is not None:
-            op, losses = train_ligo(op, small_params, cfg1, cfg2, data_it,
-                                    steps=ligo_steps, lr=ligo_lr,
-                                    momentum=ligo_momentum,
-                                    loss_chunk=loss_chunk, engine=engine,
-                                    scan_chunk=ligo_scan_chunk,
-                                    phase_ckpt=ligo_ckpt,
-                                    phase_meta=ligo_meta,
-                                    fail_at=ligo_fail_at,
-                                    ledger=ligo_ledger,
-                                    ledger_ctx=ligo_ledger_ctx)
-            info["ligo_losses"] = losses
-    else:
-        raise ValueError(method)
-    info["operator"] = op
-    if not apply:
-        return None, info
-    big = apply_ligo(op, small_params, cfg1, cfg2, engine=engine)
-    if opt_state is not None:
-        if grow_optimizer:
-            from repro.optim import grow_adamw_state
-            info["opt_state"] = grow_adamw_state(opt_state, op, cfg1, cfg2,
-                                                 engine=engine)
+    with obs.span("grow", method=method, src=cfg1.name, dst=cfg2.name):
+        key = key if key is not None else jax.random.PRNGKey(0)
+        info: Dict[str, Any] = {"method": method}
+        _validate_opt_state(opt_state, small_params)
+        if method == "random":
+            big = init_params(cfg2, key)
+            if opt_state is not None:
+                from repro.optim import adamw_init
+                info["opt_state"] = adamw_init(big)
+            return big, info
+        if method == "stackbert":
+            op = ops.stackbert_operator(cfg1, cfg2, key=key)
+        elif method == "interpolation":
+            op = ops.interpolation_operator(cfg1, cfg2, key=key)
+        elif method == "net2net":
+            op = ops.net2net_operator(key, cfg1, cfg2)
+        elif method == "bert2bert":
+            op = ops.bert2bert_operator(key, cfg1, cfg2)
+        elif method == "lemon":
+            op = ops.lemon_operator(cfg1, cfg2)
+        elif method == "upcycle":
+            from repro.core.upcycle import upcycle_operator
+            op = upcycle_operator(cfg1, cfg2)
+        elif method == "gqa_merge":
+            op = ops.gqa_merge_operator(cfg1, cfg2)
+        elif method == "ligo":
+            with obs.span("ligo.init"):
+                op = init_ligo_params(key, cfg1, cfg2, depth_init=depth_init)
+            if ligo_steps and data_it is not None:
+                with obs.span("ligo.phase", steps=ligo_steps):
+                    op, losses = train_ligo(
+                        op, small_params, cfg1, cfg2, data_it,
+                        steps=ligo_steps, lr=ligo_lr, momentum=ligo_momentum,
+                        loss_chunk=loss_chunk, engine=engine,
+                        scan_chunk=ligo_scan_chunk, phase_ckpt=ligo_ckpt,
+                        phase_meta=ligo_meta, fail_at=ligo_fail_at,
+                        ledger=ligo_ledger, ledger_ctx=ligo_ledger_ctx)
+                info["ligo_losses"] = losses
         else:
-            from repro.optim import adamw_init
-            info["opt_state"] = adamw_init(big)
-    return big, info
+            raise ValueError(method)
+        info["operator"] = op
+        if not apply:
+            return None, info
+        with obs.span("grow.params"):
+            big = apply_ligo(op, small_params, cfg1, cfg2, engine=engine)
+        if opt_state is not None:
+            if grow_optimizer:
+                from repro.optim import grow_adamw_state
+                with obs.span("grow.moments"):
+                    info["opt_state"] = grow_adamw_state(
+                        opt_state, op, cfg1, cfg2, engine=engine)
+            else:
+                from repro.optim import adamw_init
+                info["opt_state"] = adamw_init(big)
+        return big, info

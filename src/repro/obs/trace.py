@@ -25,18 +25,35 @@ module); ``dur_ms`` is the span's wall time. Spans are recorded at *exit*
 (they carry ``dur_ms``); ordering in the ring is therefore by end time —
 sort by ``t_ms`` to rebuild the timeline. A span that exits via an
 exception carries an ``error`` field with the exception repr.
+
+**On the device trace's clock.** Once jax is imported, every span also
+enters ``jax.profiler.TraceAnnotation(name, **attrs)`` for its lifetime,
+with the scalar attrs given at entry as the annotation's arguments (attrs
+written mid-span reach the ring, not the profiler). A profiled run
+(``--obs-profile DIR``) then shows the program's spans beside the device
+ops they launch. Outside a profiler session an annotation costs about a
+microsecond.
+
+**Compiles by span.** At the first span after jax is imported, a
+``jax.monitoring`` listener is registered (once) for the backend compile
+event. Each compile adds to the counter groups ``jax.compiles`` (count) and
+``jax.compile_s`` (seconds), keyed by the innermost open span on the
+compiling thread (``"none"`` outside every span), and records a
+``jax.compile`` event (``span``, ``secs``) in the ring.
 """
 from __future__ import annotations
 
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
 from repro.obs import _state
+from repro.obs.metrics import counter_group
 
 __all__ = [
     "FlightRecorder", "FLIGHT", "span", "event", "flight_dump",
@@ -46,13 +63,20 @@ __all__ = [
 set_enabled = _state.set_enabled
 enabled = _state.enabled
 
-_EPOCH = time.perf_counter()
+# ``time.perf_counter()`` at which the records' ``t_ms`` reads 0
+EPOCH = time.perf_counter()
 _SPAN_IDS = itertools.count(1)
 _TLS = threading.local()
 
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILES = counter_group("jax.compiles")
+_COMPILE_S = counter_group("jax.compile_s")
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, once jax is imported
+_HOOK_LOCK = threading.Lock()
+
 
 def _now_ms() -> float:
-    return (time.perf_counter() - _EPOCH) * 1e3
+    return (time.perf_counter() - EPOCH) * 1e3
 
 
 class FlightRecorder:
@@ -151,10 +175,41 @@ def flight_dump(reason: str) -> Optional[str]:
 
 
 def _stack() -> list:
+    """This thread's open spans, outermost first, as ``(span_id, name)``."""
     st = getattr(_TLS, "stack", None)
     if st is None:
         st = _TLS.stack = []
     return st
+
+
+def _on_duration(name: str, secs: float, **_) -> None:
+    if name != COMPILE_EVENT:
+        return
+    st = _stack()
+    where = st[-1][1] if st else "none"
+    _COMPILES.inc(where)
+    _COMPILE_S.inc(where, secs)
+    event("jax.compile", span=where, secs=round(secs, 6))
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` once the process has imported jax
+    (None before: obs itself never imports it). The first call that finds
+    jax also registers the compile listener."""
+    global _ANNOTATION
+    if _ANNOTATION is None and sys.modules.get("jax") is not None:
+        with _HOOK_LOCK:
+            if _ANNOTATION is None:
+                import jax
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_duration)
+                _ANNOTATION = jax.profiler.TraceAnnotation
+    return _ANNOTATION
+
+
+def _scalars(attrs: Dict[str, object]) -> Dict[str, object]:
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (bool, int, float, str))}
 
 
 class _Span:
@@ -162,7 +217,8 @@ class _Span:
     the block to attach facts discovered mid-span (e.g. the cache-migration
     mode picked); read ``dur_ms`` after the block for the measured wall."""
 
-    __slots__ = ("name", "attrs", "span_id", "parent_id", "_t0", "dur_ms")
+    __slots__ = ("name", "attrs", "span_id", "parent_id", "_t0", "dur_ms",
+                 "_ann")
 
     def __init__(self, name: str, attrs: Dict[str, object]):
         self.name = name
@@ -171,18 +227,25 @@ class _Span:
         self.parent_id: Optional[int] = None
         self._t0 = 0.0
         self.dur_ms: Optional[float] = None
+        self._ann = None
 
     def __enter__(self) -> "_Span":
         st = _stack()
-        self.parent_id = st[-1] if st else None
-        st.append(self.span_id)
+        self.parent_id = st[-1][0] if st else None
+        st.append((self.span_id, self.name))
+        ann = _annotation()
+        if ann is not None:
+            self._ann = ann(self.name, **_scalars(self.attrs))
+            self._ann.__enter__()
         self._t0 = _now_ms()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = _now_ms()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         st = _stack()
-        if st and st[-1] == self.span_id:
+        if st and st[-1][0] == self.span_id:
             st.pop()
         self.dur_ms = round(t1 - self._t0, 3)
         rec = {
@@ -199,20 +262,22 @@ class _Span:
 
 
 class _NoopSpan:
-    __slots__ = ("attrs", "dur_ms")
+    """A disabled span: records nothing and annotates nothing, but still
+    times its block, so callers that read ``dur_ms`` work either way."""
+
+    __slots__ = ("attrs", "dur_ms", "_t0")
 
     def __init__(self):
         self.attrs: Dict[str, object] = {}
         self.dur_ms: Optional[float] = None
 
     def __enter__(self) -> "_NoopSpan":
+        self._t0 = _now_ms()
         return self
 
     def __exit__(self, *a) -> bool:
+        self.dur_ms = round(_now_ms() - self._t0, 3)
         return False
-
-
-_NOOP = _NoopSpan()
 
 
 def span(name: str, **attrs):
@@ -229,7 +294,7 @@ def event(name: str, **attrs) -> None:
     st = _stack()
     FLIGHT.record({
         "type": "event", "name": name,
-        "parent_id": st[-1] if st else None,
+        "parent_id": st[-1][0] if st else None,
         "thread": threading.current_thread().name,
         "t_ms": round(_now_ms(), 3),
         "attrs": attrs,

@@ -414,15 +414,17 @@ class ServingEngine:
             req = self.queue.pop()
             if req is None:
                 return
-            self._h_queue_wait.observe(
-                (time.perf_counter() - req.t_submit) * 1e3)
+            wait_ms = (time.perf_counter() - req.t_submit) * 1e3
+            self._h_queue_wait.observe(wait_ms)
             req.true_len = len(req.prompt)
             if self.alloc is not None:
                 self.alloc.admit(slot, req.true_len, self._worst_len(req))
             toks = np.zeros((1, self.prompt_budget), np.int32)
             toks[0, :req.true_len] = req.prompt
-            with obs.span("serve.prefill", slot=slot, uid=req.uid,
-                          prompt_len=req.true_len):
+            # admission to the first token picked on the host
+            with obs.span("serve.prefill", uid=req.uid, slot=slot,
+                          prompt_len=req.true_len,
+                          queue_wait_ms=round(wait_ms, 3)):
                 out = self._prefill(self.params, jnp.asarray(toks),
                                     jnp.asarray(req.true_len))
                 logits, caches = out[0], out[1]
@@ -430,20 +432,20 @@ class ServingEngine:
                     self._sync_state(self.state), caches,
                     jnp.asarray(req.true_len, jnp.int32),
                     jnp.asarray(slot, jnp.int32))
-            self.pos_host[slot] = req.true_len
-            if self.keep_residual:
-                h = np.asarray(out[2][0], np.float32)
-                self.resid[slot, :req.true_len] = h[:req.true_len]
-                self.resid_from[slot] = 0
-            if self.d_cfg is not None:
-                d_out = self._d_prefill(self.d_params, jnp.asarray(toks),
-                                        jnp.asarray(req.true_len))
-                self.d_state = self._d_insert(
-                    self._sync_state(self.d_state), d_out[1],
-                    jnp.asarray(req.true_len, jnp.int32),
-                    jnp.asarray(slot, jnp.int32))
-            req.tokens.append(self._pick_token(req, np.asarray(logits)))
-            req.t_first = time.perf_counter()
+                self.pos_host[slot] = req.true_len
+                if self.keep_residual:
+                    h = np.asarray(out[2][0], np.float32)
+                    self.resid[slot, :req.true_len] = h[:req.true_len]
+                    self.resid_from[slot] = 0
+                if self.d_cfg is not None:
+                    d_out = self._d_prefill(self.d_params, jnp.asarray(toks),
+                                            jnp.asarray(req.true_len))
+                    self.d_state = self._d_insert(
+                        self._sync_state(self.d_state), d_out[1],
+                        jnp.asarray(req.true_len, jnp.int32),
+                        jnp.asarray(slot, jnp.int32))
+                req.tokens.append(self._pick_token(req, np.asarray(logits)))
+                req.t_first = time.perf_counter()
             self._h_ttft.observe((req.t_first - req.t_submit) * 1e3)
             req.status, req.slot = "running", slot
             self.slot_req[slot] = req
@@ -492,21 +494,22 @@ class ServingEngine:
         for i, r in active:
             last[i, 0] = r.tokens[-1]
         state = self._sync_state(self.state)
-        t0 = time.perf_counter()
-        out = self._decode(self.params, state, jnp.asarray(last))
-        logits = out[0]
-        logits.block_until_ready()
-        self._observe_step((time.perf_counter() - t0) * 1e3)
+        with obs.span("serve.decode", active=len(active)) as sp:
+            out = self._decode(self.params, state, jnp.asarray(last))
+            logits = out[0]
+            logits.block_until_ready()
+        self._observe_step(sp.dur_ms)
         self.decode_steps += 1
         self.state = out[1]
-        L = np.asarray(logits)
-        if self.keep_residual:
-            h = np.asarray(out[2][:, 0], np.float32)
-        for i, r in active:
+        with obs.span("serve.sample", active=len(active)):
+            L = np.asarray(logits)
             if self.keep_residual:
-                self.resid[i, self.pos_host[i]] = h[i]
-            r.tokens.append(self._pick_token(r, L[i]))
-            self._finish_if_done(r)
+                h = np.asarray(out[2][:, 0], np.float32)
+            for i, r in active:
+                if self.keep_residual:
+                    self.resid[i, self.pos_host[i]] = h[i]
+                r.tokens.append(self._pick_token(r, L[i]))
+                self._finish_if_done(r)
 
     def _spec_round(self, active) -> None:
         K = self.spec_k
@@ -518,24 +521,35 @@ class ServingEngine:
             last[i, 0] = r.tokens[-1]
         d_state = self._sync_state(self.d_state)
         state = self._sync_state(self.state)
-        t0 = time.perf_counter()
-        if self.temperature > 0:
-            keys = spec.draft_keys(self.seed, self.spec_stats["rounds"],
-                                   K + 1, self.slots)
-            toks, probs, d_state2 = self._draft(self.d_params, d_state,
-                                                jnp.asarray(last), keys)
-        else:
-            toks, probs, d_state2 = self._draft(self.d_params, d_state,
-                                                jnp.asarray(last))
-        toks.block_until_ready()
-        t1 = time.perf_counter()
-        draft_toks = np.asarray(toks)
-        inputs = np.concatenate([last, draft_toks.astype(np.int32)], axis=1)
-        v_out = self._verify(self.params, state, jnp.asarray(inputs))
-        v_out[0].block_until_ready()
-        t2 = time.perf_counter()
-        self._observe_step((t2 - t0) * 1e3)
+        with obs.span("serve.decode", active=len(active), spec=K) as sp:
+            t0 = time.perf_counter()
+            if self.temperature > 0:
+                keys = spec.draft_keys(self.seed, self.spec_stats["rounds"],
+                                       K + 1, self.slots)
+                toks, probs, d_state2 = self._draft(self.d_params, d_state,
+                                                    jnp.asarray(last), keys)
+            else:
+                toks, probs, d_state2 = self._draft(self.d_params, d_state,
+                                                    jnp.asarray(last))
+            toks.block_until_ready()
+            t1 = time.perf_counter()
+            draft_toks = np.asarray(toks)
+            inputs = np.concatenate([last, draft_toks.astype(np.int32)],
+                                    axis=1)
+            v_out = self._verify(self.params, state, jnp.asarray(inputs))
+            v_out[0].block_until_ready()
+            t2 = time.perf_counter()
+        self._observe_step(sp.dur_ms)
         self.decode_steps += 1
+        with obs.span("serve.sample", active=len(active), spec=K):
+            acc_total = self._accept(active, v_out, draft_toks, probs,
+                                     d_state2)
+        self._spec_telemetry(len(active), acc_total, t1 - t0, t2 - t1)
+
+    def _accept(self, active, v_out, draft_toks, probs, d_state2) -> int:
+        """Take each slot's accepted draft tokens and the verifier's own;
+        returns how many draft tokens were accepted in all."""
+        K = self.spec_k
         L = np.asarray(v_out[0])                       # (slots, K+1, V)
         hid = (np.asarray(v_out[1], np.float32)
                if self.keep_residual else None)
@@ -560,7 +574,7 @@ class ServingEngine:
                 self.resid[i, p0:p0 + K + 1] = hid[i]
             self._append_tokens(r, emit)
             self._finish_if_done(r)
-        self._spec_telemetry(len(active), acc_total, t1 - t0, t2 - t1)
+        return acc_total
 
     def _spec_telemetry(self, n_active: int, acc_total: int,
                         t_draft: float, t_verify: float) -> None:

@@ -183,11 +183,10 @@ class HopController:
         memoised, so the real hop pays a dispatch) and fixes the cold-start
         bug: the first *live* hop is judged against a measured budget
         instead of a bare timeout it might legitimately exceed."""
-        t0 = time.perf_counter()
         with obs.span("hop.warm", src=self.engine.cfg.name,
-                      dst=self.cfg2.name):
+                      dst=self.cfg2.name) as sp:
             buf = self._grow_once()
-        dt = time.perf_counter() - t0
+        dt = sp.dur_ms / 1e3
         del buf
         self.watchdog.seed(dt)
         print(f"[hop] warmed grow path in {dt * 1e3:.1f} ms "
