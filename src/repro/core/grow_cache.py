@@ -102,23 +102,26 @@ def kv_cache_expanders(ligo: Dict, cfg1: ModelConfig, cfg2: ModelConfig):
 
 
 def _expand_kv(C: jax.Array, E: jax.Array, cfg2: ModelConfig) -> jax.Array:
-    """Apply a flat-kv-space expander per cached position:
-    (lead, B, S, KV1, dh1) → (lead, B, S, KV2, dh2)."""
-    lead = C.shape[:-2]
+    """Apply a flat-kv-space expander per cached position, keeping the
+    layout: a dense cache (L, B, S, KV1, dh1) → (L, B, S, KV2, dh2), a paged
+    pool (L, n_blocks, bs, KV1·dh1) → (L, n_blocks, bs, KV2·dh2)."""
+    lead = C.shape[:3]
     flat = C.reshape(lead + (-1,))
     out = jnp.einsum("...i,oi->...o", flat.astype(jnp.float32),
-                     jnp.asarray(E, jnp.float32))
-    return out.astype(C.dtype).reshape(
-        lead + (cfg2.n_kv_heads, cfg2.d_head))
+                     jnp.asarray(E, jnp.float32)).astype(C.dtype)
+    if C.ndim == 4:
+        return out
+    return out.reshape(lead + (cfg2.n_kv_heads, cfg2.d_head))
 
 
 def grow_attn_caches(caches: Dict[str, jax.Array], ligo: Dict,
                      cfg1: ModelConfig, cfg2: ModelConfig, *,
                      depth: str = "strict") -> Dict[str, jax.Array]:
-    """Grow a stacked attention cache ``{"k","v"}: (L1,B,S,KV1,dh1)`` to the
-    big architecture. ``depth="strict"`` (the serving default) refuses
-    non-identity depth blends — a blended cache is an approximation, and the
-    engine's re-prefill fallback is both exact and cheap at serving sequence
+    """Grow a stacked attention cache ``{"k","v"}: (L1,B,S,KV1,dh1)``, or
+    paged pools ``(L1, n_blocks, bs, KV1·dh1)``, to the big architecture.
+    ``depth="strict"`` (the serving default) refuses non-identity depth
+    blends — a blended cache is an approximation, and the engine's
+    re-prefill fallback is both exact and cheap at serving sequence
     lengths. ``depth="blend"`` applies the operator's ``wk``/``wv`` layer
     blends anyway (benchmarks, experiments)."""
     E_k, E_v = kv_cache_expanders(ligo, cfg1, cfg2)
@@ -151,7 +154,7 @@ def grow_decode_state(state: Dict[str, Any], ligo: Dict, cfg1: ModelConfig,
 
     Paged states (a ``"pages"`` entry; ``serving.kv_pages``) grow
     *per-block*: the expander applies position-wise, so the block pool
-    ``(L, n_blocks, bs, KV1, dh1)`` grows exactly like a dense row and the
+    ``(L, n_blocks, bs, KV1·dh1)`` grows exactly like a dense row and the
     page table / allocator ride through untouched (block geometry is
     independent of the grown feature dims).
 
@@ -264,13 +267,13 @@ def replay_grow_state(state: Dict[str, Any], params2, cfg1: ModelConfig,
     if paged:
         table = state["pages"]                  # (slots, P)
         nb, bs = state["caches"]["k"].shape[1:3]
+        feat = state["caches"]["k"].shape[3:]   # (KV·dh,)
         tgt = jnp.where(table >= 0, table, nb)  # unmapped → dropped
 
         def rows_to_pool(rows):
             L_new, slots = rows.shape[:2]
-            blocks = rows.reshape(L_new, slots, cap // bs, bs,
-                                  *rows.shape[3:])
-            pool = jnp.zeros((L_new, nb, bs) + rows.shape[3:], rows.dtype)
+            blocks = rows.reshape((L_new, slots, cap // bs, bs) + feat)
+            pool = jnp.zeros((L_new, nb, bs) + feat, rows.dtype)
             return pool.at[:, tgt].set(blocks)
 
         new_k, new_v = rows_to_pool(new_k), rows_to_pool(new_v)

@@ -40,9 +40,11 @@ from repro.kernels.ligo_expand import (fused_eligible, fused_vmem_bytes,
                                        _blend_expand_grouped)
 from repro.kernels.ligo_expand_bwd import (ligo_blend_expand_bwd_fused as
                                            _bwd_fused)
+from repro.kernels.paged_attention import page_fits
+from repro.kernels.paged_attention import paged_attention as _paged_attention
 from repro.obs import CounterGroup, counter_group
 
-# Trace-time fused-kernel launch counter ({"fwd": n, "bwd": n} per trace),
+# Trace-time kernel launch counter ({"fwd": n, "bwd": n, "paged_attn": n}),
 # thread-safe (locked), registered in the obs registry as "kernels.launches".
 LAUNCH_COUNTS: CounterGroup = counter_group("kernels.launches")
 
@@ -178,6 +180,26 @@ def ligo_blend_expand_vjp(w, B, W, *, use_kernel=None):
     out = _blend_expand_grouped_vjp(bool(use_kernel), w[None], B,
                                     W[None, :, None])
     return out[0, :, 0]
+
+
+def paged_kernel_ok(block_size: int, features: int, mesh=None) -> bool:
+    """Does a paged decode round read its pools ``(L, n_blocks, block_size,
+    features)`` in place with the paged-attention kernel? Only on the TPU
+    (elsewhere the gather path, as the LiGO kernels take their reference),
+    on one device (no ``pallas_call`` under GSPMD partitioning), and for
+    pages of whole tiles (:func:`page_fits`)."""
+    return (not _interpret() and (mesh is None or mesh.size == 1)
+            and page_fits(block_size, features))
+
+
+def paged_attention(q, k_new, v_new, k_pool, v_pool, layer, pages, lens):
+    """One decode step of attention over stacked paged pools, reading only
+    each slot's live pages of ``layer`` (see
+    :mod:`repro.kernels.paged_attention`). Counted per trace under
+    ``LAUNCH_COUNTS["paged_attn"]``."""
+    LAUNCH_COUNTS.inc("paged_attn")
+    return _paged_attention(q, k_new, v_new, k_pool, v_pool, layer, pages,
+                            lens, interpret=_interpret())
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, **kw):

@@ -274,7 +274,7 @@ def _serve_live(args, cfg, params, mesh):
                   "drafter never adopted or queue drained pre-hop)")
     if engine.alloc is not None:
         a = engine.alloc
-        pool = engine.state["caches"]["k"]   # (L, n_blocks, bs, KV, dh)
+        pool = engine.state["caches"]["k"]   # (L, n_blocks, bs, KV·dh)
         elt = jnp.dtype(pool.dtype).itemsize
         block_bytes = 2 * pool.shape[0] * int(np.prod(pool.shape[2:])) * elt
         dense_bytes = block_bytes // a.block_size * engine.cap

@@ -20,10 +20,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import P, maybe_shard
+from repro.kernels import ops as kernel_ops
 from repro.models import seqmix
 from repro.models.layers import (apply_mlp, apply_norm, apply_mrope, apply_rope,
                                  attention, decode_attention, dense_init,
-                                 init_mlp, init_norm, paged_decode_attention)
+                                 init_mlp, init_norm, paged_decode_attention,
+                                 paged_write_targets)
 from repro.models.moe import apply_moe, init_moe
 
 
@@ -78,29 +80,40 @@ def _qkv(p, h, cfg, positions):
 def apply_attn(p, x, cfg, positions, *, mode: str = "train",
                cache: Optional[dict] = None, cur_len=None,
                chunk_q: int = 2048, chunk_k: int = 2048,
-               p_bf16: bool = False, pages=None):
+               p_bf16: bool = False, pages=None, layer=None):
     """Returns (x_out, new_cache_or_None, aux_loss).
 
     ``pages`` (decode only): a (B, P) int32 page table switching the cache
     to the paged layout — ``cache`` leaves are then block pools
-    ``(n_blocks, block_size, KV, dh)`` shared across slots, written through
+    ``(n_blocks, block_size, KV·dh)`` shared across slots, written through
     the table (unmapped targets are dropped, see ``serving.kv_pages``) and
     read via :func:`paged_decode_attention`. Requires per-slot ``cur_len``
     ((B,)) and full-context attention (no window).
+
+    ``layer`` (with ``pages``): ``cache`` leaves are the whole stacked pools
+    ``(L, n_blocks, block_size, KV·dh)`` and the paged-attention kernel
+    reads this layer's live pages in place; the returned cache is the new
+    token's ``{"k", "v"}: (B, KV·dh)``, which the caller writes into the
+    pools after the layer loop.
     """
     B, T, D = x.shape
     h = apply_norm(p["ln1"], x, cfg.norm)
     new_cache = None
-    if mode == "decode" and pages is not None:
+    if mode == "decode" and layer is not None:
+        q, k, v = _qkv(p, h, cfg, positions)              # T == 1
+        lens = jnp.broadcast_to(cur_len - 1, (B,))
+        o = kernel_ops.paged_attention(
+            q[:, 0], k[:, 0], v[:, 0], cache["k"], cache["v"], layer,
+            pages, lens)[:, None]
+        new_cache = {"k": k[:, 0].reshape(B, -1), "v": v[:, 0].reshape(B, -1)}
+    elif mode == "decode" and pages is not None:
         assert not cfg.window, "paged KV requires full-context attention"
         q, k, v = _qkv(p, h, cfg, positions)              # T == 1
         n_blocks, bs = cache["k"].shape[:2]
-        pos = (cur_len - 1).astype(jnp.int32)             # (B,)
-        blk, off = pos // bs, pos % bs
-        page = jnp.take_along_axis(pages, blk[:, None], axis=1)[:, 0]
-        tgt = jnp.where(page >= 0, page, n_blocks)        # OOB → dropped
-        k_cache = cache["k"].at[tgt, off].set(k[:, 0])
-        v_cache = cache["v"].at[tgt, off].set(v[:, 0])
+        tgt, off = paged_write_targets(pages, (cur_len - 1).astype(jnp.int32),
+                                       n_blocks, bs)
+        k_cache = cache["k"].at[tgt, off].set(k[:, 0].reshape(B, -1))
+        v_cache = cache["v"].at[tgt, off].set(v[:, 0].reshape(B, -1))
         o = paged_decode_attention(q, k_cache, v_cache, pages, cur_len)
         new_cache = {"k": k_cache, "v": v_cache}
     elif mode == "decode":
@@ -156,13 +169,13 @@ def init_moe_block(key, cfg, dtype=jnp.float32):
 
 def apply_moe_block(p, x, cfg, positions, *, mode="train", cache=None,
                     cur_len=None, chunk_q=2048, chunk_k=2048, p_bf16=False,
-                    pages=None):
+                    pages=None, layer=None):
     # attention sub-block (reuse apply_attn without its MLP)
     p_attn = {k: v for k, v in p.items() if k != "moe"}
     x, new_cache, _ = apply_attn(p_attn, x, cfg, positions, mode=mode,
                                  cache=cache, cur_len=cur_len,
                                  chunk_q=chunk_q, chunk_k=chunk_k,
-                                 p_bf16=p_bf16, pages=pages)
+                                 p_bf16=p_bf16, pages=pages, layer=layer)
     h = apply_norm(p["ln2"], x, cfg.norm)
     if cfg.moe_impl == "shard_map":
         from repro.models.moe_shardmap import (apply_moe_shardmap,

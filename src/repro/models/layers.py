@@ -218,23 +218,38 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return o.reshape(B, 1, H, dh).astype(q.dtype)
 
 
+def paged_write_targets(pages: jax.Array, pos: jax.Array, n_blocks: int,
+                        block_size: int):
+    """Where each slot's token at ``pos`` lands in a paged pool: (block,
+    offset), both (B,). An unmapped page (-1) redirects the block to
+    ``n_blocks``, one past the pool, so a scatter drops the write instead of
+    landing it in another slot's block (see ``serving.kv_pages``)."""
+    blk, off = pos // block_size, pos % block_size
+    page = jnp.take_along_axis(pages, blk[:, None], axis=1)[:, 0]
+    return jnp.where(page >= 0, page, n_blocks), off
+
+
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, pages: jax.Array,
                            cur_len: jax.Array) -> jax.Array:
     """Single-step attention over a *paged* KV cache.
 
-    q: (B, 1, H, dh); k_pool/v_pool: (n_blocks, block_size, KV, dh) — the
-    shared block pool; pages: (B, P) int32 page table (-1 = unmapped;
-    negative indices wrap on gather, which is safe because every position
-    ``>= cur_len`` is masked and unmapped pages only cover those). The
-    gather materialises each slot's (P*block_size) view, then the math is
-    exactly :func:`decode_attention` (full-context only — windowed caches
-    stay on the dense ring-buffer layout).
+    q: (B, 1, H, dh); k_pool/v_pool: (n_blocks, block_size, KV·dh) — one
+    layer's block pool, a position's heads flat (``(…, KV, dh)`` pools work
+    too); pages: (B, P) int32 page table (-1 = unmapped; negative indices
+    wrap on gather, which is safe because every position ``>= cur_len`` is
+    masked and unmapped pages only cover those). The gather materialises
+    each slot's (P*block_size) view, then the math is exactly
+    :func:`decode_attention` (full-context only — windowed caches stay on
+    the dense ring-buffer layout). The serving engine on one device reads
+    the pools in place instead (``kernels.paged_attention``); this is that
+    kernel's oracle and the sharded engine's path.
     """
     B, P = pages.shape
     bs = k_pool.shape[1]
-    k = k_pool[pages].reshape(B, P * bs, *k_pool.shape[2:])
-    v = v_pool[pages].reshape(B, P * bs, *v_pool.shape[2:])
+    dh = q.shape[-1]
+    k = k_pool[pages].reshape(B, P * bs, -1, dh)
+    v = v_pool[pages].reshape(B, P * bs, -1, dh)
     return decode_attention(q, k, v, cur_len)
 
 

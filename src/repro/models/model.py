@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import P, maybe_shard
 from repro.models import blocks as B
-from repro.models.layers import apply_norm, embed_init, init_norm
+from repro.models.layers import (apply_norm, embed_init, init_norm,
+                                 paged_write_targets)
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
@@ -130,17 +131,24 @@ def _maybe_remat(fn, remat: bool):
 
 def _fwd_homogeneous(params, x, cfg, positions, *, mode, caches, cur_len,
                      remat, chunk_q, chunk_k, act_spec=None, p_bf16=False,
-                     pages=None):
+                     pages=None, paged_kernel=False):
     kind = cfg.blocks[0]
+    # paged decode with the kernel: the layers read the stacked pools in
+    # place (the scan walks layer indices, not pool slices) and hand back
+    # their new token's K/V, written into the pools once after the loop
+    in_place = mode == "decode" and pages is not None and paged_kernel
 
     def body(carry, inp):
         h, aux = carry
         p, c = inp
+        layer = None
+        if in_place:
+            c, layer = caches, c
         if kind in _APPLY:
             h, nc, a = _APPLY[kind](p, h, cfg, positions, cache=c, mode=mode,
                                     cur_len=cur_len, chunk_q=chunk_q,
                                     chunk_k=chunk_k, p_bf16=p_bf16,
-                                    pages=pages)
+                                    pages=pages, layer=layer)
         else:
             h, nc, a = _SEQ_APPLY[kind](p, h, cfg, mode=mode, cache=c)
         if act_spec is not None:
@@ -149,10 +157,26 @@ def _fwd_homogeneous(params, x, cfg, positions, *, mode, caches, cur_len,
             nc = None
         return (h, aux + a), nc
 
+    per_layer = jnp.arange(caches["k"].shape[0]) if in_place else caches
     (x, aux), new_caches = jax.lax.scan(
         _maybe_remat(body, remat), (x, jnp.zeros((), jnp.float32)),
-        (params["layers"][kind], caches))
+        (params["layers"][kind], per_layer))
+    if in_place:
+        new_caches = _write_step_paged(caches, pages, cur_len - 1, new_caches)
     return x, new_caches, aux
+
+
+def _write_step_paged(pools, pages, pos, toks):
+    """Every layer's new token into the stacked pools, one scatter per pool:
+    pools ``{"k","v"}: (L, n_blocks, bs, F)``, toks ``(L, B, F)``, pos (B,).
+    Unmapped targets are dropped."""
+    L, n_blocks, bs = pools["k"].shape[:3]
+    tgt, off = paged_write_targets(pages, pos.astype(jnp.int32), n_blocks, bs)
+    # every (layer, slot) indexed, one F-wide row each: a window over the
+    # layer dim would make XLA relayout the whole pool around the scatter
+    layer = jnp.arange(L)[:, None]
+    return {n: pools[n].at[layer, tgt[None], off[None]].set(toks[n])
+            for n in ("k", "v")}
 
 
 def _fwd_xlstm(params, x, cfg, *, mode, caches, remat, act_spec=None):
@@ -222,7 +246,7 @@ def forward(params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
             mode: str = "train", caches=None, cur_len=None,
             remat: bool = False, chunk_q: int = 2048, chunk_k: int = 2048,
             act_spec=None, p_bf16: bool = False, pages=None,
-            return_prenorm: bool = False):
+            paged_kernel: bool = False, return_prenorm: bool = False):
     """Returns (hidden (B,T,D), new_caches, aux_loss) — plus the
     pre-final-norm residual stream as a 4th element when
     ``return_prenorm=True`` (the serving engine preserves it so a
@@ -231,7 +255,9 @@ def forward(params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
 
     ``pages``: (B, P) page table switching attention caches to the paged
     block-pool layout (decode mode, attention-cache families only; see
-    ``serving.kv_pages``).
+    ``serving.kv_pages``). ``paged_kernel`` reads the pools in place with
+    the paged-attention kernel instead of gathering each layer's pool (the
+    engine decides, ``kernels.ops.paged_kernel_ok``).
 
     ``act_spec``: optional PartitionSpec pinned onto the residual stream
     between blocks (e.g. P("data", "model", None) = Megatron-style sequence
@@ -261,7 +287,8 @@ def forward(params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
         x, new_caches, aux = _fwd_homogeneous(
             params, x, cfg, positions, mode=mode, caches=caches,
             cur_len=cur_len, remat=remat, chunk_q=chunk_q, chunk_k=chunk_k,
-            act_spec=act_spec, p_bf16=p_bf16, pages=pages)
+            act_spec=act_spec, p_bf16=p_bf16, pages=pages,
+            paged_kernel=paged_kernel)
     prenorm = x
     x = apply_norm(params["final_norm"], x, cfg.norm)
     if return_prenorm:
@@ -315,16 +342,19 @@ def init_decode_state(cfg: ModelConfig, batch_size: int, seq_len: int):
 
 
 def decode_step(params, cfg: ModelConfig, state, batch: Dict[str, jax.Array],
-                *, return_prenorm: bool = False) -> Tuple[jax.Array, Any]:
+                *, return_prenorm: bool = False,
+                paged_kernel: bool = False) -> Tuple[jax.Array, Any]:
     """One-token decode: batch["tokens"]: (B, 1). Returns (logits (B,V), state).
 
     A ``state["pages"]`` entry switches attention caches to the paged
-    layout; the table rides through unchanged (the host owns it). With
-    ``return_prenorm`` the result is (logits, state, prenorm (B,1,D))."""
+    layout; the table rides through unchanged (the host owns it), and
+    ``paged_kernel`` has the layers read the pools in place (see
+    :func:`forward`). With ``return_prenorm`` the result is (logits, state,
+    prenorm (B,1,D))."""
     cur_len = state["pos"] + 1
     out = forward(params, cfg, batch, mode="decode", caches=state["caches"],
                   cur_len=cur_len, pages=state.get("pages"),
-                  return_prenorm=return_prenorm)
+                  paged_kernel=paged_kernel, return_prenorm=return_prenorm)
     hidden, new_caches = out[0], out[1]
     logits = unembed(params, cfg, hidden[:, -1])
     new_state = {"caches": new_caches, "pos": cur_len}

@@ -139,7 +139,10 @@ class FlightRecorder:
         return path
 
 
-FLIGHT = FlightRecorder()
+# Room for a whole traced window: a serving loop at tens of decode rounds a
+# second records about a hundred spans a second, and the window's readers
+# need its start (the hop a third of the way in) as much as its end.
+FLIGHT = FlightRecorder(capacity=1 << 16)
 
 _DUMP_DIR: Optional[str] = None
 _DUMP_SEQ = itertools.count(1)

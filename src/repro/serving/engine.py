@@ -40,6 +40,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs.base import ModelConfig
+from repro.kernels import ops as kernel_ops
 from repro.models.model import (_pad_attn_caches, decode_step, forward,
                                 init_decode_state, unembed)
 from repro.serving import speculative as spec
@@ -51,25 +52,55 @@ _EMA = 0.3          # telemetry smoothing for acceptance / launch costs
 _RECENT_STEPS = 4096  # exact-window size behind the step_times_ms shim
 
 
+class _CachesDonated:
+    """Call a program that donates its state's caches with the state whole.
+
+    ``jax.jit`` donates whole arguments, and only the caches may go: the
+    page table is the allocator's cached device copy and the positions may
+    be shared with other holders (a migrated state keeps its source's). So
+    the program takes ``(…, caches, rest, …)`` and this splits the state
+    argument at position ``at`` into those two."""
+
+    def __init__(self, jitted, at: int):
+        self.jitted, self.at = jitted, at
+
+    def _split(self, args):
+        state = args[self.at]
+        rest = {k: v for k, v in state.items() if k != "caches"}
+        return args[:self.at] + (state["caches"], rest) + args[self.at + 1:]
+
+    def __call__(self, *args):
+        return self.jitted(*self._split(args))
+
+    def lower(self, *args):
+        return self.jitted.lower(*self._split(args))
+
+
 @functools.lru_cache(maxsize=16)
 def make_serving_fns(cfg: ModelConfig, cap: int, layout: str = "dense",
-                     want_hidden: bool = False):
+                     want_hidden: bool = False, paged_kernel: bool = False):
     """(prefill_one, decode_many, insert) jitted for one architecture.
 
-    Memoised on ``(cfg, cap, layout, want_hidden)`` (configs are frozen
-    dataclasses): a hop back to an architecture the process has already
-    served — or a second engine on the same config — reuses the compiled
-    programs instead of re-tracing, so ``install`` costs reference flips,
-    not compiles.
+    Memoised on ``(cfg, cap, layout, want_hidden, paged_kernel)`` (configs
+    are frozen dataclasses): a hop back to an architecture the process has
+    already served — or a second engine on the same config — reuses the
+    compiled programs instead of re-tracing, so ``install`` costs reference
+    flips, not compiles.
 
     ``cap`` is the cache row capacity: the (window-clamped) ``max_len`` for
     the dense layout, the page-aligned ``padded_len`` for the paged one.
     With ``layout="paged"`` the state carries ``{"caches": pools, "pos",
     "pages"}`` and ``insert`` scatters the prefilled row into the slot's
-    pages; decode gathers through the table. ``want_hidden`` additionally
-    returns the pre-final-norm residual stream (prefill: (1, Tp, D);
-    decode: (B, 1, D)) — the engine preserves it per slot so a depth-only
-    hop can replay just the new layers (``core.grow_cache``).
+    pages; decode reads through the table — in place with the
+    paged-attention kernel when ``paged_kernel``
+    (:meth:`ServingEngine.paged_kernel`), else by gathering each layer's
+    pool. ``want_hidden`` additionally returns the
+    pre-final-norm residual stream (prefill: (1, Tp, D); decode: (B, 1, D))
+    — the engine preserves it per slot so a depth-only hop can replay just
+    the new layers (``core.grow_cache``).
+
+    ``decode_many`` and ``insert`` donate the state's caches: the state
+    passed in is spent, and the caller keeps the one returned.
 
     ``prefill_one`` takes a right-padded (1, Tp) prompt plus its true
     length; padding positions write garbage cache entries *beyond* the
@@ -90,34 +121,33 @@ def make_serving_fns(cfg: ModelConfig, cap: int, layout: str = "dense",
             return logits, caches, out[3]
         return logits, caches
 
-    @jax.jit
-    def decode_many(params, state, tokens):
-        return decode_step(params, cfg, state, {"tokens": tokens},
-                           return_prenorm=want_hidden)
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode_many(params, caches, rest, tokens):
+        return decode_step(params, cfg, {**rest, "caches": caches},
+                           {"tokens": tokens}, return_prenorm=want_hidden,
+                           paged_kernel=paged_kernel)
 
     if layout == "dense":
-        @jax.jit
-        def insert(state, caches1, pos1, slot):
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def insert(caches, rest, caches1, pos1, slot):
             # every cache leaf (attn K/V, ssm conv/state) carries batch at
             # axis 1
             ins = lambda c, c1: jax.lax.dynamic_update_slice_in_dim(  # noqa: E731,E501
                 c, c1, slot, axis=1)
-            new = {"caches": jax.tree.map(ins, state["caches"], caches1),
-                   "pos": state["pos"].at[slot].set(pos1)}
-            if "pages" in state:
-                new["pages"] = state["pages"]
-            return new
+            return {**rest, "caches": jax.tree.map(ins, caches, caches1),
+                    "pos": rest["pos"].at[slot].set(pos1)}
     else:
-        @jax.jit
-        def insert(state, caches1, pos1, slot):
-            pages_row = state["pages"][slot]          # (P,)
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def insert(caches, rest, caches1, pos1, slot):
+            pages_row = rest["pages"][slot]           # (P,)
             sc = lambda pool, c1: scatter_row_blocks(  # noqa: E731
                 pool, pages_row, c1[:, 0])
-            return {"caches": jax.tree.map(sc, state["caches"], caches1),
-                    "pos": state["pos"].at[slot].set(pos1),
-                    "pages": state["pages"]}
+            return {"caches": jax.tree.map(sc, caches, caches1),
+                    "pos": rest["pos"].at[slot].set(pos1),
+                    "pages": rest["pages"]}
 
-    return prefill_one, decode_many, insert
+    return (prefill_one, _CachesDonated(decode_many, 1),
+            _CachesDonated(insert, 0))
 
 
 class ServingEngine:
@@ -166,6 +196,8 @@ class ServingEngine:
         self._h_ttft = obs.histogram("serve.request.ttft_ms")
         self._h_tok_s = obs.histogram("serve.request.tokens_per_s",
                                       buckets=obs.RATE_BUCKETS)
+        self._h_pages = obs.histogram("serve.kv.pages_read",
+                                      buckets=obs.LOG10_BUCKETS)
         self._h_draft = obs.histogram("serve.spec.draft_ms")
         self._h_verify = obs.histogram("serve.spec.verify_ms")
         self._g_acc = obs.gauge("serve.spec.acc_ema")
@@ -217,6 +249,13 @@ class ServingEngine:
             return self.alloc.padded_len
         return min(cfg.window, self.max_len) if cfg.window else self.max_len
 
+    def paged_kernel(self, cfg: ModelConfig) -> bool:
+        """Does ``cfg``'s decode round read the pools in place with the
+        paged-attention kernel (``kernels.ops.paged_kernel_ok``: on one TPU,
+        pages of whole tiles)? Otherwise it gathers through the table."""
+        return self.alloc is not None and kernel_ops.paged_kernel_ok(
+            self.alloc.block_size, cfg.n_kv_heads * cfg.d_head, self.mesh)
+
     def fresh_state(self, cfg: ModelConfig):
         if self.kv_layout == "paged":
             return {"caches": init_paged_caches(cfg, self.alloc.n_blocks,
@@ -235,7 +274,8 @@ class ServingEngine:
             assert paged_supported(cfg), \
                 f"{cfg.name}: paged KV unsupported; use kv_layout='dense'"
         cap = self._cap_for(cfg)
-        fns = make_serving_fns(cfg, cap, self.kv_layout, self.keep_residual)
+        fns = make_serving_fns(cfg, cap, self.kv_layout, self.keep_residual,
+                               self.paged_kernel(cfg))
         if state is None:
             state = self.fresh_state(cfg)
         if obs.active_ledger() is not None:
@@ -286,14 +326,17 @@ class ServingEngine:
             self.d_cfg = self.d_params = self.d_state = None
             return False
         self._d_prefill, _, self._d_insert = make_serving_fns(
-            cfg1, cap, self.kv_layout, False)
+            cfg1, cap, self.kv_layout, False, self.paged_kernel(cfg1))
         if self.temperature > 0:
             self._draft = spec.make_sampled_draft_fn(
-                cfg1, self.spec_k, self.temperature, self.top_p)
+                cfg1, self.spec_k, self.temperature, self.top_p,
+                self.paged_kernel(cfg1))
         else:
-            self._draft = spec.make_draft_fn(cfg1, self.spec_k)
+            self._draft = spec.make_draft_fn(cfg1, self.spec_k,
+                                             self.paged_kernel(cfg1))
         self._verify = spec.make_verify_fn(self.cfg, self.spec_k + 1,
-                                           self.keep_residual)
+                                           self.keep_residual,
+                                           self.paged_kernel(self.cfg))
         self.spec_enabled = True
         self.spec_stats = {"rounds": 0, "accepted": 0, "drafted": 0,
                            "acc_ema": None, "first_round_acc": None,
@@ -486,6 +529,19 @@ class ServingEngine:
                 self._plain_round(active)
         return self.has_work()
 
+    def _round_attrs(self, active, ahead: int) -> Dict[str, int]:
+        """The ``serve.decode`` span's attributes: the live slots and, paged,
+        the pages the round touches — each slot's pages up to the farthest
+        position it writes (``ahead`` past its own), which is what the
+        decode program reads; also observed as ``serve.kv.pages_read``."""
+        attrs = {"active": len(active)}
+        if self.alloc is not None:
+            attrs["pages"] = sum(
+                self.alloc.pages_for(int(self.pos_host[i]) + ahead)
+                for i, _ in active)
+            self._h_pages.observe(attrs["pages"])
+        return attrs
+
     def _plain_round(self, active) -> None:
         if self.alloc is not None:
             for i, _ in active:
@@ -494,7 +550,7 @@ class ServingEngine:
         for i, r in active:
             last[i, 0] = r.tokens[-1]
         state = self._sync_state(self.state)
-        with obs.span("serve.decode", active=len(active)) as sp:
+        with obs.span("serve.decode", **self._round_attrs(active, 1)) as sp:
             out = self._decode(self.params, state, jnp.asarray(last))
             logits = out[0]
             logits.block_until_ready()
@@ -521,7 +577,8 @@ class ServingEngine:
             last[i, 0] = r.tokens[-1]
         d_state = self._sync_state(self.d_state)
         state = self._sync_state(self.state)
-        with obs.span("serve.decode", active=len(active), spec=K) as sp:
+        with obs.span("serve.decode", spec=K,
+                      **self._round_attrs(active, K + 1)) as sp:
             t0 = time.perf_counter()
             if self.temperature > 0:
                 keys = spec.draft_keys(self.seed, self.spec_stats["rounds"],
@@ -622,8 +679,6 @@ class ServingEngine:
         under ``params``/``cfg``. Exact by construction (it *is* the grown
         model's own prefill), at the cost of one prompt-length forward per
         live session."""
-        prefill_one, _, insert = make_serving_fns(
-            cfg, self._cap_for(cfg), self.kv_layout, self.keep_residual)
         state = self.fresh_state(cfg)
         for slot, req in enumerate(self.slot_req):
             if req is None:
@@ -631,14 +686,33 @@ class ServingEngine:
             # cache holds prompt + all generated tokens except the newest
             # (decode writes its *input* token); same layout re-derived here
             hist = (list(req.prompt) + list(req.tokens))[:-1]
-            toks = np.zeros((1, self.max_len), np.int32)
-            toks[0, :len(hist)] = hist
-            out = prefill_one(params, jnp.asarray(toks),
-                              jnp.asarray(len(hist)))
-            state = insert(self._sync_paged(state), out[1],
-                           jnp.asarray(len(hist), jnp.int32),
-                           jnp.asarray(slot, jnp.int32))
+            state = self._reprefill_slot(params, cfg, state, slot, hist)
         return state
+
+    def warm_reprefill(self, params, cfg: ModelConfig) -> None:
+        """Compile :meth:`reprefill_state`'s programs for ``cfg`` by
+        re-prefilling one token into the first two slots of a fresh state
+        (discarded: the first insert takes a fresh state, later ones the
+        state an insert returned), so a hop that meets live sessions
+        compiles nothing."""
+        if self.kv_layout == "paged" and not paged_supported(cfg):
+            return                     # the hop refuses such a target
+        state = self.fresh_state(cfg)
+        for slot in range(min(2, self.slots)):
+            state = self._reprefill_slot(params, cfg, state, slot, [0])
+        jax.block_until_ready(state)
+
+    def _reprefill_slot(self, params, cfg: ModelConfig, state, slot: int,
+                        hist: List[int]):
+        prefill_one, _, insert = make_serving_fns(
+            cfg, self._cap_for(cfg), self.kv_layout, self.keep_residual,
+            self.paged_kernel(cfg))
+        toks = np.zeros((1, self.max_len), np.int32)
+        toks[0, :len(hist)] = hist
+        out = prefill_one(params, jnp.asarray(toks), jnp.asarray(len(hist)))
+        return insert(self._sync_paged(state), out[1],
+                      jnp.asarray(len(hist), jnp.int32),
+                      jnp.asarray(slot, jnp.int32))
 
     def _sync_paged(self, state):
         if self.alloc is not None:

@@ -182,11 +182,14 @@ class HopController:
         time. This both pre-compiles the grow (the plan executor is
         memoised, so the real hop pays a dispatch) and fixes the cold-start
         bug: the first *live* hop is judged against a measured budget
-        instead of a bare timeout it might legitimately exceed."""
+        instead of a bare timeout it might legitimately exceed. The grown
+        model's re-prefill (the cache migration's fallback) is compiled
+        too, outside the watchdog's measure."""
         with obs.span("hop.warm", src=self.engine.cfg.name,
                       dst=self.cfg2.name) as sp:
             buf = self._grow_once()
         dt = sp.dur_ms / 1e3
+        self.engine.warm_reprefill(buf, self.cfg2)
         del buf
         self.watchdog.seed(dt)
         print(f"[hop] warmed grow path in {dt * 1e3:.1f} ms "

@@ -13,8 +13,12 @@ Split of responsibilities:
   never traced — the engine consults it between decode launches.
 - The device ops below (:func:`gather_pages`, :func:`write_token_paged`,
   :func:`scatter_row_blocks`) run inside the jitted serving programs against
-  pools shaped ``(n_blocks, block_size, KV, dh)`` (stacked over layers by
-  the model-level scan) and a traced snapshot of the page table.
+  pools shaped ``(L, n_blocks, block_size, KV·dh)`` and a traced snapshot
+  of the page table. A position's heads lie flat in the last dim, so a
+  block is one contiguous, lane-dense ``(block_size, KV·dh)`` slab in HBM:
+  the TPU lays a ``(…, KV, dh)`` pool out with the block index minor-most,
+  which scatters a block across the whole pool. The one-device decode
+  round reads the live blocks in place (``kernels.paged_attention``).
 
 Masking convention (load-bearing): an unmapped page is ``-1`` in the table.
 jax gathers treat negative indices numpy-style (they *wrap*), so reads
@@ -40,6 +44,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs.base import ModelConfig
+from repro.models.layers import paged_write_targets
 
 
 def paged_supported(cfg: ModelConfig) -> bool:
@@ -158,15 +163,17 @@ class PageAllocator:
 # ---------------------------------------------------------------------------
 def init_paged_caches(cfg: ModelConfig, n_blocks: int,
                       block_size: int) -> Dict[str, jax.Array]:
-    """Zeroed K/V pools ``(L, n_blocks, block_size, KV, dh)``."""
+    """Zeroed K/V pools ``(L, n_blocks, block_size, KV·dh)``."""
     from repro.models.model import DTYPES
     dtype = DTYPES[cfg.dtype]
-    shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads, cfg.d_head)
+    shape = (cfg.n_layers, n_blocks, block_size,
+             cfg.n_kv_heads * cfg.d_head)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def gather_pages(pool: jax.Array, pages: jax.Array) -> jax.Array:
-    """(n_blocks, bs, KV, dh) gathered through (B, P) → (B, P*bs, KV, dh).
+    """(n_blocks, bs, *F) gathered through (B, P) → (B, P*bs, *F), F a
+    position's features (``KV·dh`` in the engine's pools).
 
     Unmapped (-1) pages wrap to the pool tail — harmless, those positions
     are ``>= cur_len`` and masked by decode attention (see module doc)."""
@@ -179,34 +186,32 @@ def write_token_paged(pool: jax.Array, pages: jax.Array,
                       pos: jax.Array, kv: jax.Array) -> jax.Array:
     """Write one token per slot at its own position through the page table.
 
-    pool: (n_blocks, bs, KV, dh); pages: (B, P); pos: (B,); kv: (B, 1, KV, dh).
-    Unmapped targets redirect out of bounds → the scatter drops them.
+    pool: (n_blocks, bs, *F); pages: (B, P); pos: (B,); kv: (B, 1, KV, dh)
+    with KV·dh = prod(F). Unmapped targets redirect out of bounds → the
+    scatter drops them.
     """
-    bs = pool.shape[1]
-    n_blocks = pool.shape[0]
-    blk, off = pos // bs, pos % bs
-    page = jnp.take_along_axis(pages, blk[:, None], axis=1)[:, 0]
-    tgt = jnp.where(page >= 0, page, n_blocks)
-    return pool.at[tgt, off].set(kv[:, 0])
+    tgt, off = paged_write_targets(pages, pos, pool.shape[0], pool.shape[1])
+    return pool.at[tgt, off].set(kv[:, 0].reshape(kv.shape[:1]
+                                                  + pool.shape[2:]))
 
 
 def scatter_row_blocks(pool: jax.Array, pages_row: jax.Array,
                        row: jax.Array) -> jax.Array:
     """Insert a dense cache row into the pool via one slot's page table.
 
-    pool: (L, n_blocks, bs, KV, dh); pages_row: (P,); row: (L, P*bs, KV, dh)
+    pool: (L, n_blocks, bs, *F); pages_row: (P,); row: (L, P*bs, KV, dh)
     — the prefill-produced row padded to the page-aligned length.
     """
     L, n_blocks, bs = pool.shape[:3]
     P = pages_row.shape[0]
-    blocks = row.reshape(L, P, bs, *row.shape[2:])
+    blocks = row.reshape((L, P, bs) + pool.shape[3:])
     tgt = jnp.where(pages_row >= 0, pages_row, n_blocks)
     return pool.at[:, tgt].set(blocks)
 
 
 def gathered_dense_view(pool: jax.Array, table: jax.Array) -> jax.Array:
-    """Materialise the dense ``(L, B, P*bs, KV, dh)`` view of a pool — the
+    """Materialise the dense ``(L, B, P*bs, *F)`` view of a pool — the
     bridge back to every dense-layout consumer (cache growth oracles,
-    parity tests). Unmapped pages come back as whatever block they wrap to;
+    parity tests; the engine's pools have F = KV·dh). Unmapped pages come back as whatever block they wrap to;
     callers mask by position exactly like decode attention does."""
     return jax.vmap(lambda pl: gather_pages(pl, table))(pool)

@@ -116,7 +116,7 @@ def device_adjust_probs(logits: jax.Array, temperature: float,
 # Draft / verify programs (memoised per (cfg, K, ...))
 # ---------------------------------------------------------------------------
 @functools.lru_cache(maxsize=32)
-def make_draft_fn(cfg: ModelConfig, K: int):
+def make_draft_fn(cfg: ModelConfig, K: int, paged_kernel: bool = False):
     """Greedy drafter: one launch scans K+1 decode steps of the small
     model, feeding each argmax forward. Returns (tokens (B,K),
     logits (B,K,V), state).
@@ -126,14 +126,15 @@ def make_draft_fn(cfg: ModelConfig, K: int):
     K-th draft's cache entry) unwritten — a hole the drafter would decode
     across on the next round whenever the verifier accepted everything.
     The extra step's output token is discarded; its cache write is the
-    point."""
+    point. ``paged_kernel`` as in ``decode_step``."""
     BUILD_COUNTS.inc("draft")
 
     @jax.jit
     def draft(params, state, last):
         def body(carry, _):
             st, tok = carry
-            logits, st2 = decode_step(params, cfg, st, {"tokens": tok})
+            logits, st2 = decode_step(params, cfg, st, {"tokens": tok},
+                                      paged_kernel=paged_kernel)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (st2, nxt[:, None]), (nxt, logits)
 
@@ -147,7 +148,7 @@ def make_draft_fn(cfg: ModelConfig, K: int):
 
 @functools.lru_cache(maxsize=32)
 def make_sampled_draft_fn(cfg: ModelConfig, K: int, temperature: float,
-                          top_p: float):
+                          top_p: float, paged_kernel: bool = False):
     """Sampled drafter: same scan, but each step draws from the adjusted
     distribution with a per-(step, slot) key. Returns (tokens (B,K),
     probs (B,K,V) — the exact distributions sampled from — and state).
@@ -161,7 +162,8 @@ def make_sampled_draft_fn(cfg: ModelConfig, K: int, temperature: float,
     def draft(params, state, last, keys):        # keys: (K+1, B, 2) uint32
         def body(carry, keys_k):
             st, tok = carry
-            logits, st2 = decode_step(params, cfg, st, {"tokens": tok})
+            logits, st2 = decode_step(params, cfg, st, {"tokens": tok},
+                                      paged_kernel=paged_kernel)
             probs = device_adjust_probs(logits, temperature, top_p)
             nxt = jax.vmap(
                 lambda kk, pp: jax.random.categorical(
@@ -185,7 +187,8 @@ def draft_keys(seed: int, round_idx: int, K: int, slots: int) -> jax.Array:
 
 
 @functools.lru_cache(maxsize=32)
-def make_verify_fn(cfg: ModelConfig, K1: int, want_hidden: bool):
+def make_verify_fn(cfg: ModelConfig, K1: int, want_hidden: bool,
+                   paged_kernel: bool = False):
     """Verifier: one launch scans the grown model's decode body over the
     K+1 given inputs (no feedback — the tokens are fixed), yielding all
     K+1 next-token logits. The body is the same ``decode_step`` the vanilla
@@ -199,7 +202,8 @@ def make_verify_fn(cfg: ModelConfig, K1: int, want_hidden: bool):
     def verify(params, state, inputs):                # inputs: (B, K1)
         def body(st, tok_col):                        # tok_col: (B,)
             out = decode_step(params, cfg, st, {"tokens": tok_col[:, None]},
-                              return_prenorm=want_hidden)
+                              return_prenorm=want_hidden,
+                              paged_kernel=paged_kernel)
             if want_hidden:
                 return out[1], (out[0], out[2][:, 0])
             return out[1], (out[0],)

@@ -335,3 +335,28 @@ def test_warm_seeds_watchdog_and_survives_tight_timeout(small_params):
         hop.poll()
     assert hop.completed                 # would be a watchdog abort cold
     assert all(r.status == "done" for r in reqs)
+
+
+@pytest.mark.parametrize("kv_layout", ["paged", "dense"])
+def test_warm_compiles_the_reprefill(small_params, kv_layout):
+    """warm() also compiles the grown model's re-prefill, so a hop that
+    meets live sessions and re-prefills them compiles nothing inside its
+    cache-grow stage."""
+    from repro import obs
+    wide = WIDE.scaled(name=f"spec-wide-warm-{kv_layout}")   # a cold jit
+    eng = ServingEngine(small_params, TINY, slots=2, prompt_budget=8,
+                        gen_budget=8, kv_layout=kv_layout)
+    hop = HopController(eng, wide, lemon_operator(TINY, wide),
+                        cache_mode="reprefill", background=False)
+    hop.warm()
+    compiles = obs.counter_group("jax.compiles")
+    before = compiles.get("hop.cache-grow")
+    reqs = [eng.submit([1, 2, 3], max_new=8), eng.submit([4, 5], max_new=8)]
+    eng.step()
+    eng.step()
+    hop.begin()
+    assert len(eng.live) == 2 and hop.poll()
+    assert hop.completed and hop.cache_path == "reprefill"
+    assert compiles.get("hop.cache-grow") == before
+    eng.run()
+    assert all(r.status == "done" for r in reqs)
