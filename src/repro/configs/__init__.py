@@ -9,8 +9,8 @@ from repro.configs.base import (ALL_SHAPES, ATTN, MAMBA2, MLSTM, MOE, SLSTM,
                                 TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)
 from repro.configs import (deepseek_coder_33b, hubert_xlarge, llama3_8b,
                            mixtral_8x7b, phi4_mini_3_8b, qwen2_vl_72b,
-                           qwen3_moe_30b_a3b, starcoder2_7b, xlstm_125m,
-                           zamba2_2_7b)
+                           qwen3_moe_30b_a3b, starcoder2_3b, starcoder2_7b,
+                           xlstm_125m, zamba2_2_7b)
 from repro.configs.paper_models import GROWTH_PAIRS, PAPER_MODELS
 
 ASSIGNED: Dict[str, ModelConfig] = {
@@ -20,7 +20,12 @@ ASSIGNED: Dict[str, ModelConfig] = {
               zamba2_2_7b, qwen2_vl_72b)
 }
 
-REGISTRY: Dict[str, ModelConfig] = {**ASSIGNED, **PAPER_MODELS}
+# Published growth sources of assigned architectures (not assigned cells)
+SOURCES: Dict[str, ModelConfig] = {
+    m.CONFIG.name: m.CONFIG for m in (starcoder2_3b,)
+}
+
+REGISTRY: Dict[str, ModelConfig] = {**ASSIGNED, **SOURCES, **PAPER_MODELS}
 
 
 def get_config(name: str) -> ModelConfig:

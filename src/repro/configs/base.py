@@ -66,6 +66,7 @@ class ModelConfig:
     # --- MLP / norm ---
     act: str = "swiglu"              # swiglu | gelu
     norm: str = "rms"                # rms | layer
+    norm_eps: float = 1e-6           # epsilon under the norm's square root
     tie_embeddings: bool = False
 
     # --- modality frontends (stubs; see DESIGN.md §4) ---

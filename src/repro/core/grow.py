@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.ligo import apply_ligo, init_ligo_params
 from repro.core import operators as ops
+from repro.distributed.sharding import current_mesh, mesh_attrs
 from repro.models.losses import loss_fn
 from repro.models.model import init_params
 from repro import obs
@@ -42,11 +43,52 @@ from repro import obs
 TRACE_COUNTS: obs.CounterGroup = obs.counter_group("core.traces")
 
 
+def saved_activation_bytes(cfg: ModelConfig, batch: int, seq: int,
+                           sizes: Dict[str, int]) -> int:
+    """What one device keeps of ``cfg``'s activations for a backward pass
+    over a ``batch`` x ``seq`` batch, with the batch split over the mesh
+    axis ``data`` and the heads and feed-forward width over ``model``
+    (``sizes``: axis sizes; a dim that does not divide stays whole): per
+    layer and token, f32 attention scores and probabilities (1.5 copies),
+    about eight model-wide and three feed-forward-wide f32 activations."""
+    def per(n, axis):
+        k = sizes.get(axis, 1)
+        return n // k if n % k == 0 else n
+    b, h, ff = per(batch, "data"), per(cfg.n_heads, "model"), \
+        per(cfg.d_ff, "model")
+    per_token = 6 * h * seq + 4 * (8 * cfg.d_model + 3 * ff)
+    return cfg.n_layers * b * seq * per_token
+
+
+def ligo_remat_needed(cfg2: ModelConfig, batch_shape,
+                      bytes_limit: Optional[int] = None) -> bool:
+    """Recompute the grown layers in the LiGO phase's backward pass when
+    keeping their activations would take more than half of a device's
+    memory (``bytes_limit``; by default the first local device's, and no
+    recomputation where the backend reports none, as on the CPU). The
+    ambient mesh gives the split of the batch and of the layers."""
+    if bytes_limit is None:
+        stats = jax.local_devices()[0].memory_stats() or {}
+        bytes_limit = stats.get("bytes_limit")
+    if not bytes_limit:
+        return False
+    mesh = current_mesh()
+    sizes = dict(mesh.shape) if mesh is not None else {}
+    return saved_activation_bytes(cfg2, int(batch_shape[0]),
+                                  int(batch_shape[1]), sizes) \
+        > bytes_limit / 2
+
+
 def ligo_loss(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
               batch, *, loss_chunk: int = 0, engine: str = "plan"
               ) -> jax.Array:
+    """The task loss of the grown model; its backward recomputes the grown
+    layers where :func:`ligo_remat_needed` says so from the shapes."""
+    x = next((batch[k] for k in ("tokens", "frames", "patches")
+              if k in batch), None)           # (batch, positions, ...)
+    remat = x is not None and ligo_remat_needed(cfg2, x.shape)
     big = apply_ligo(ligo, small_params, cfg1, cfg2, engine=engine)
-    loss, _ = loss_fn(big, cfg2, batch, loss_chunk=loss_chunk)
+    loss, _ = loss_fn(big, cfg2, batch, loss_chunk=loss_chunk, remat=remat)
     return loss
 
 
@@ -246,11 +288,12 @@ def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
     chunks_done = 0
     h_chunk = obs.histogram("ligo.chunk_ms")
     h_ckpt = obs.histogram("ligo.checkpoint_ms")
+    where = mesh_attrs()
     while done < steps:
         n = min(chunk, steps - done)
         # host-boundary timing: float(l) on the losses forces the sync, so
         # the span wall covers the whole compiled chunk, never intrudes on it
-        with obs.span("ligo.chunk", start=done, n=n) as sp_chunk:
+        with obs.span("ligo.chunk", start=done, n=n, **where) as sp_chunk:
             with obs.span("ligo.batches", n=n):
                 raw = [next(data_it) for _ in range(n)]
                 if ledger is not None and led_state["tokens"] is None:
@@ -369,9 +412,19 @@ def grow(small_params, cfg1: ModelConfig, cfg2: ModelConfig, *,
     the LiGO phase elastic — threaded straight into :func:`train_ligo`'s
     phase-checkpointing (see its docstring) — and
     ``ligo_ledger``/``ligo_ledger_ctx`` give the phase's per-step records
-    to the compute ledger the same way.
+    to the compute ledger the same way. The phase recomputes the grown
+    layers in its backward pass where their activations would not fit
+    beside it (:func:`ligo_remat_needed`, from the shapes).
+
+    Under an ambient mesh (``jax.set_mesh``) every program of the hop runs
+    sharded: the phase's chunk program over the batches as they are
+    sharded, and the growth of the parameters and moments through the
+    plan's sharded executor, whose outputs land as ``params_pspecs``
+    prescribes. The ``grow`` span and each ``ligo.chunk`` span carry the
+    mesh (``mesh``, ``devices``).
     """
-    with obs.span("grow", method=method, src=cfg1.name, dst=cfg2.name):
+    with obs.span("grow", method=method, src=cfg1.name, dst=cfg2.name,
+                  **mesh_attrs()):
         key = key if key is not None else jax.random.PRNGKey(0)
         info: Dict[str, Any] = {"method": method}
         _validate_opt_state(opt_state, small_params)

@@ -62,7 +62,11 @@ traced apply each group's stacked contraction gets a sharding constraint, and
 the fused Pallas path runs the grouped custom_vjp **per shard** under
 ``shard_map`` (:func:`repro.kernels.ligo_blend_expand_grouped_sharded`): the
 kernel only contracts the blend (L1) and expansion (A) dims, so sharding the
-trailing output dim (or the group dim) needs no cross-device traffic. Callers
+trailing output dim (or the group dim) needs no cross-device traffic. A group
+takes that route when the kernels take the shape one shard's launch sees
+(:meth:`GrowthPlan.fused_routes`), else the einsum contractions; every
+executor build counts its groups by route (``ligo.groups_fused``,
+``ligo.groups_einsum`` in the obs registry). Callers
 that sit under an ambient mesh (``jax.set_mesh`` — the train/serve
 drivers) pick this up automatically through ``apply_ligo``.
 
@@ -102,13 +106,19 @@ from repro.core.ligo import (_flatten, _kind_counts, _unflatten,
                              resolve_expander)
 from repro.distributed.sharding import (named_shardings, params_pspecs,
                                         physical_spec)
+from repro import obs
 from repro.kernels.ops import (LAUNCH_COUNTS, fused_eligible,
                                ligo_blend_expand_grouped_sharded,
-                               ligo_blend_expand_grouped_vjp)
+                               ligo_blend_expand_grouped_vjp, shard_split)
 
 # Trace-time instrumentation (tests assert expanders are resolved once per
 # apply-trace, not once per leaf, and that train_ligo never re-traces).
 RESOLVE_COUNTS: Counter = Counter()
+# Leaf groups by route, counted once per executor build: on the fused
+# kernels (under ``shard_map`` when the executor has a mesh), and on the
+# einsum contractions. Their sum over a build is the plan's group count.
+GROUPS_FUSED = obs.counter("ligo.groups_fused")
+GROUPS_EINSUM = obs.counter("ligo.groups_einsum")
 
 ExprRef = Tuple[Any, str]          # (hashable expr key, role) — plan.exprs key
 
@@ -155,6 +165,9 @@ class LeafGroup:
     out_kind: str = ""             # target stack kind when it differs
     out_paths: Tuple[str, ...] = ()  # target leaf paths when renamed
     bcast: int = 0                 # expert-replication count (0 = none)
+    # what fused_eligible weighed (L2, grown in-dim i, itemsize), for the
+    # per-shard eligibility under a mesh; None off the kernel route
+    fuse_dims: Optional[Tuple[int, int, int]] = None
 
     @property
     def dst_kind(self) -> str:
@@ -222,11 +235,12 @@ def _plan_group(kind: str, stacked: bool, paths, shape, in_e, out_e,
     # Fused Pallas eligibility: stacked (L1, a, b) or MoE (L1, E, a, b) with
     # an in-expander — G/E fold into the grid, ragged dims are masked
     # in-kernel, so only the kernels' VMEM limit can reject a real shape.
-    kernel_ok = (blended and in_e is not None and len(shape) in (3, 4)
-                 and fused_eligible(L1, L2, extra, i, a, b, G=len(paths),
-                                    itemsize=itemsize))
+    fusable = blended and in_e is not None and len(shape) in (3, 4)
+    kernel_ok = fusable and fused_eligible(L1, L2, extra, i, a, b,
+                                           G=len(paths), itemsize=itemsize)
     return LeafGroup(kind, stacked, tuple(paths), tuple(shape), in_ref,
-                     out_ref, False, order, kernel_ok)
+                     out_ref, False, order, kernel_ok,
+                     fuse_dims=(L2, i, itemsize) if fusable else None)
 
 
 class GrowthPlan:
@@ -258,19 +272,50 @@ class GrowthPlan:
         """(groups that take the fused Pallas route, all groups)."""
         return sum(g.kernel_ok for g in self.groups), len(self.groups)
 
-    def _report_route(self) -> None:
+    @staticmethod
+    def shard_shape(g: LeafGroup, mesh: Optional[Mesh]) -> Tuple[int, int]:
+        """(G, Bd): the group count and trailing dim of the stack one
+        shard's kernel launch sees under ``mesh`` (the whole stack's
+        without one, or when neither dim divides over it)."""
+        G, b = len(g.paths), g.shape[-1]
+        if mesh is None:
+            return G, b
+        axes_b, axes_g = shard_split(G, b, mesh)
+        for ax in axes_b:
+            b //= mesh.shape[ax]
+        for ax in axes_g:
+            G //= mesh.shape[ax]
+        return G, b
+
+    def fused_routes(self, use_kernel: bool,
+                     mesh: Optional[Mesh] = None) -> Tuple[bool, ...]:
+        """Per group: does an apply run it on the fused kernels? Under a
+        mesh a group is eligible at the shape one shard's launch sees;
+        the others take the einsum contractions."""
+        out = []
+        for g in self.groups:
+            ok = use_kernel and g.fuse_dims is not None
+            if ok:
+                L2, i, itemsize = g.fuse_dims
+                G, b = self.shard_shape(g, mesh)
+                extra = g.shape[1] if len(g.shape) == 4 else 1
+                ok = fused_eligible(g.shape[0], L2, extra, i, g.shape[-2], b,
+                                    G=G, itemsize=itemsize)
+            out.append(bool(ok))
+        return tuple(out)
+
+    def _report_route(self, fused: Tuple[bool, ...]) -> None:
         """Say once per plan which groups a kernel-enabled apply runs on the
         einsum contractions instead of the fused kernels."""
         if self._route_reported:
             return
         self._route_reported = True
-        k, n = self.kernel_groups()
         einsum = [f"{g.kind or 'top'}/{g.paths[0]}{list(g.shape)}"
                   + (f"x{len(g.paths)}" if len(g.paths) > 1 else "")
-                  for g in self.groups if not g.kernel_ok]
-        print(f"[plan] {self.cfg1.name} -> {self.cfg2.name}: {k}/{n} groups "
-              f"on the fused kernels; einsum route: {', '.join(einsum)}",
-              file=sys.stderr)
+                  for g, f in zip(self.groups, fused) if not f]
+        print(f"[plan] {self.cfg1.name} -> {self.cfg2.name}: {sum(fused)}/"
+              f"{len(fused)} groups on the fused kernels; einsum route: "
+              f"{', '.join(einsum)}", file=sys.stderr)
 
     # -- resolution cache (one resolve per distinct (expr, role) per apply) --
     def _expander_table(self, width) -> Dict[ExprRef, jax.Array]:
@@ -365,8 +410,9 @@ class GrowthPlan:
         """
         if use_kernel is None:
             use_kernel = jax.default_backend() == "tpu"
+        fused = self.fused_routes(use_kernel, mesh)
         if use_kernel:
-            self._report_route()
+            self._report_route(fused)
         group_sh = (self._group_shardings(mesh)
                     if mesh is not None and constrain_groups else None)
         width = ligo["width"]
@@ -396,7 +442,7 @@ class GrowthPlan:
             E_in = table[g.in_ref] if g.in_ref is not None else None
             E_out = table[g.out_ref] if g.out_ref is not None else None
             X = leaves[0][None] if len(leaves) == 1 else jnp.stack(leaves)
-            if use_kernel and g.kernel_ok and w_g is not None:
+            if fused[gidx] and w_g is not None:
                 out = self._run_group_fused(g, X, E_in, E_out, w_g, mesh=mesh)
             else:
                 if use_kernel:
@@ -439,6 +485,11 @@ class GrowthPlan:
         """
         key = (use_kernel, mesh, square)
         if key not in self._executors:
+            fused = self.fused_routes(jax.default_backend() == "tpu"
+                                      if use_kernel is None else use_kernel,
+                                      mesh)
+            GROUPS_FUSED.inc(sum(fused))
+            GROUPS_EINSUM.inc(len(fused) - sum(fused))
             if mesh is None:
                 fn = functools.partial(GrowthPlan.apply, self,
                                        use_kernel=use_kernel, square=square)

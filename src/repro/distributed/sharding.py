@@ -33,6 +33,17 @@ def current_mesh() -> Optional[Mesh]:
     return None if m.empty else m
 
 
+def mesh_attrs() -> dict:
+    """Span attributes naming where the ambient mesh runs a program:
+    ``mesh``, its axis sizes joined by "x" ("2x2"; "none" outside a mesh),
+    and ``devices``, how many devices it spans."""
+    mesh = current_mesh()
+    if mesh is None:
+        return {"mesh": "none", "devices": 1}
+    return {"mesh": "x".join(str(n) for n in mesh.shape.values()),
+            "devices": int(mesh.size)}
+
+
 def physical_spec(spec: P, mesh) -> P:
     """Map logical 'data' to ('pod','data') when the mesh has a pod axis; drop
     axes the mesh doesn't have; drop shardings that don't divide evenly is left

@@ -128,6 +128,17 @@ def ligo_blend_expand_grouped_vjp(w, B, W, *, use_kernel=None):
     return _blend_expand_grouped_vjp(bool(use_kernel), w, B, W)
 
 
+def shard_split(G: int, Bd: int, mesh):
+    """How :func:`ligo_blend_expand_grouped_sharded` splits a group's
+    ``(G, L1, E, A, Bd)`` stack over ``mesh``: ``(axes_b, axes_g)``, the
+    mesh axes that shard the trailing ``Bd`` dim, or else the group dim
+    ``G`` (at most one of the two is non-empty; both empty when neither
+    divides)."""
+    from repro.distributed.sharding import divisible_axes
+    axes_b = divisible_axes(Bd, mesh)
+    return axes_b, (() if axes_b else divisible_axes(G, mesh))
+
+
 def ligo_blend_expand_grouped_sharded(w, B, W, mesh, *, use_kernel=None):
     """Grouped blend-expand distributed over ``mesh`` via ``shard_map``.
 
@@ -149,11 +160,8 @@ def ligo_blend_expand_grouped_sharded(w, B, W, mesh, *, use_kernel=None):
     if mesh is None:
         return ligo_blend_expand_grouped_vjp(w, B, W, use_kernel=use_kernel)
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import divisible_axes
 
-    G, Bd = W.shape[0], W.shape[-1]
-    axes_b = divisible_axes(Bd, mesh)
-    axes_g = () if axes_b else divisible_axes(G, mesh)
+    axes_b, axes_g = shard_split(W.shape[0], W.shape[-1], mesh)
     if axes_b:
         spec_w = P()
         spec_W = spec_out = P(None, None, None, None, axes_b)

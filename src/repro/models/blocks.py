@@ -97,7 +97,7 @@ def apply_attn(p, x, cfg, positions, *, mode: str = "train",
     pools after the layer loop.
     """
     B, T, D = x.shape
-    h = apply_norm(p["ln1"], x, cfg.norm)
+    h = apply_norm(p["ln1"], x, cfg.norm, cfg.norm_eps)
     new_cache = None
     if mode == "decode" and layer is not None:
         q, k, v = _qkv(p, h, cfg, positions)              # T == 1
@@ -151,7 +151,7 @@ def apply_attn(p, x, cfg, positions, *, mode: str = "train",
     x = x + maybe_shard(o, P("data", None, None))
     aux = jnp.zeros((), jnp.float32)
     if "mlp" in p:
-        h2 = apply_norm(p["ln2"], x, cfg.norm)
+        h2 = apply_norm(p["ln2"], x, cfg.norm, cfg.norm_eps)
         x = x + apply_mlp(p["mlp"], h2, cfg.act)
     return x, new_cache, aux
 
@@ -176,7 +176,7 @@ def apply_moe_block(p, x, cfg, positions, *, mode="train", cache=None,
                                  cache=cache, cur_len=cur_len,
                                  chunk_q=chunk_q, chunk_k=chunk_k,
                                  p_bf16=p_bf16, pages=pages, layer=layer)
-    h = apply_norm(p["ln2"], x, cfg.norm)
+    h = apply_norm(p["ln2"], x, cfg.norm, cfg.norm_eps)
     if cfg.moe_impl == "shard_map":
         from repro.models.moe_shardmap import (apply_moe_shardmap,
                                                moe_shardmap_available)
@@ -214,7 +214,7 @@ def apply_mlstm(p, x, cfg, *, mode="train", cache=None):
     di = cfg.ssm_expand * D
     H = cfg.n_heads
     dh = di // H
-    h = apply_norm(p["ln"], x, cfg.norm)
+    h = apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps)
     u = h @ p["up"]
     xi, z = jnp.split(u, 2, axis=-1)                       # (B,T,di) each
     conv_state = cache.get("conv") if cache else None
@@ -262,7 +262,7 @@ def init_slstm(key, cfg, dtype=jnp.float32):
 
 def apply_slstm(p, x, cfg, *, mode="train", cache=None):
     B, T, D = x.shape
-    h = apply_norm(p["ln"], x, cfg.norm)
+    h = apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps)
     if cache is not None:
         state = seqmix.SLSTMState(cache["h"], cache["c"], cache["n"],
                                   cache["m"])
@@ -310,7 +310,7 @@ def apply_mamba2(p, x, cfg, *, mode="train", cache=None):
     H = cfg.mamba_heads
     N = cfg.ssm_state
     dh = di // H
-    h = apply_norm(p["ln"], x, cfg.norm)
+    h = apply_norm(p["ln"], x, cfg.norm, cfg.norm_eps)
     u = h @ p["in_proj"]                                   # (B,T,2di+2N+H)
     z, xbc, dt = (u[..., :di], u[..., di:di + di + 2 * N],
                   u[..., di + di + 2 * N:])

@@ -290,7 +290,7 @@ def forward(params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
             act_spec=act_spec, p_bf16=p_bf16, pages=pages,
             paged_kernel=paged_kernel)
     prenorm = x
-    x = apply_norm(params["final_norm"], x, cfg.norm)
+    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     if return_prenorm:
         return x, new_caches, aux, prenorm
     return x, new_caches, aux

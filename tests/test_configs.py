@@ -14,6 +14,7 @@ EXPECTED_DIMS = {
     "llama3-8b": (32, 4096, 32, 8, 14336, 128256),
     "phi4-mini-3.8b": (32, 3072, 24, 8, 8192, 200064),
     "starcoder2-7b": (32, 4608, 36, 4, 18432, 49152),
+    "starcoder2-3b": (30, 3072, 24, 2, 12288, 49152),
     "deepseek-coder-33b": (62, 7168, 56, 8, 19200, 32256),
     "mixtral-8x7b": (32, 4096, 32, 8, 14336, 32000),
     "qwen3-moe-30b-a3b": (48, 2048, 32, 4, 768, 151936),
@@ -36,15 +37,15 @@ def test_cell_enumeration_counts():
     assert len(cells) == 40                       # 10 archs × 4 shapes
     runnable = [c for c in cells if c.runnable]
     skipped = [c for c in cells if not c.runnable]
-    assert len(runnable) == 32 and len(skipped) == 8
+    assert len(runnable) == 33 and len(skipped) == 7
     skip_keys = {c.key for c in skipped}
     assert "hubert-xlarge/decode_32k" in skip_keys
     assert "hubert-xlarge/long_500k" in skip_keys
-    for a in ("llama3-8b", "phi4-mini-3.8b", "starcoder2-7b",
-              "deepseek-coder-33b", "qwen3-moe-30b-a3b", "qwen2-vl-72b"):
+    for a in ("llama3-8b", "phi4-mini-3.8b", "deepseek-coder-33b",
+              "qwen3-moe-30b-a3b", "qwen2-vl-72b"):
         assert f"{a}/long_500k" in skip_keys
-    # sub-quadratic archs DO run long_500k
-    for a in ("mixtral-8x7b", "xlstm-125m", "zamba2-2.7b"):
+    # sub-quadratic archs DO run long_500k (StarCoder2: sliding window)
+    for a in ("mixtral-8x7b", "xlstm-125m", "zamba2-2.7b", "starcoder2-7b"):
         assert f"{a}/long_500k" not in skip_keys
 
 
@@ -78,3 +79,45 @@ def test_half_and_grow_configs_are_growable():
         check_growable(small, cfg)
         s = smoke_config(cfg)
         check_growable(s, grow_target(s))
+
+
+@pytest.mark.parametrize("arch,n_tied,n_untied", [
+    ("starcoder2-3b", 3_030_371_328, 3_181_366_272),
+    ("starcoder2-7b", 7_173_923_840, 7_400_416_256),
+])
+def test_starcoder2_published_block(arch, n_tied, n_untied):
+    """StarCoder2 as published: LayerNorm eps 1e-5 with biases, tanh-GELU,
+    GQA at head size 128, RoPE, a 4096 window over 16384 positions, and a
+    tied head (the tied count is the size BigCode gives)."""
+    cfg = get_config(arch)
+    assert (cfg.norm, cfg.norm_eps, cfg.act, cfg.rope, cfg.d_head,
+            cfg.window, cfg.max_seq, cfg.tie_embeddings) == (
+        "layer", 1e-5, "gelu", "rope", 128, 4096, 16384, True)
+    assert cfg.param_count() == n_tied
+    assert cfg.scaled(tie_embeddings=False).param_count() == n_untied
+
+
+def test_starcoder2_3b_grows_into_7b():
+    """The published 3B is a growth source for the 7B: group size 12 -> 9,
+    kv width 256 -> 512, and only the assigned archs count as cells."""
+    from repro.core.spec import check_growable
+    src, dst = get_config("starcoder2-3b"), get_config("starcoder2-7b")
+    check_growable(src, dst)
+    assert (src.n_heads // src.n_kv_heads, dst.n_heads // dst.n_kv_heads) \
+        == (12, 9)
+    assert (src.kv_dim, dst.kv_dim) == (256, 512)
+    assert "starcoder2-3b" not in ASSIGNED
+
+
+def test_norm_eps_reaches_every_norm():
+    """``norm_eps`` is the epsilon of the model's norms: a LayerNorm model
+    whose eps changes gives other hidden states (default 1e-6 elsewhere)."""
+    from repro.models import forward
+    cfg = smoke_config(get_config("starcoder2-7b"))
+    assert get_config("llama3-8b").norm_eps == 1e-6
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (1, 8), 0,
+                                          cfg.vocab_size)}
+    a = forward(params, cfg, batch)[0]
+    b = forward(params, cfg.scaled(norm_eps=1.0), batch)[0]
+    assert float(jax.numpy.max(jax.numpy.abs(a - b))) > 1e-3

@@ -187,7 +187,7 @@ def test_ligo_hop_spans_nest_under_grow(small_params):
             "grow/grow.params"} <= paths
     (root,) = [e for e in spans if e["name"] == "grow"]
     assert root["attrs"] == {"method": "ligo", "src": TINY.name,
-                             "dst": BIG.name}
+                             "dst": BIG.name, "mesh": "none", "devices": 1}
     launches = [e for e in spans if e["name"] == "ligo.launch"]
     assert len(launches) == 2
     # the chunk program is traced on the first launch of the phase only
