@@ -41,8 +41,9 @@ mirror the subsystem:
   ``ligo.chunk`` → ``ligo.batches`` / ``ligo.launch`` / ``ligo.sync``, and
   ``ligo.checkpoint``; then ``grow.params``, ``grow.moments``;
 - serving: ``serve.prefill`` (admission to the first token on the host),
-  ``serve.decode`` (a round's launch until its logits are ready),
-  ``serve.sample`` (logits to the host, picks, finishing);
+  ``serve.decode`` (a round's launch until its outputs are ready),
+  ``serve.sample`` (the greedy picks, or the logits rows when sampling, to
+  the host, picks, finishing; ``picked`` "device" or "host");
 - the live hop: ``hop.warm``, ``hop.grow``, ``hop.cache-grow``, ``hop.swap``;
 - trajectories: ``traj.train``, ``traj.grow``.
 

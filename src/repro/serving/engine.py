@@ -95,9 +95,14 @@ def make_serving_fns(cfg: ModelConfig, cap: int, layout: str = "dense",
     paged-attention kernel when ``paged_kernel``
     (:meth:`ServingEngine.paged_kernel`), else by gathering each layer's
     pool. ``want_hidden`` additionally returns the
-    pre-final-norm residual stream (prefill: (1, Tp, D); decode: (B, 1, D))
-    — the engine preserves it per slot so a depth-only hop can replay just
-    the new layers (``core.grow_cache``).
+    pre-final-norm residual stream (prefill: (Tp, D); decode: (B, D)) — the
+    engine preserves it per slot so a depth-only hop can replay just the new
+    layers (``core.grow_cache``).
+
+    Both programs end with the greedy pick, ``argmax`` of their logits as
+    int32 (prefill: a scalar; decode: (B,)): ``(logits, caches|state,
+    [hidden,] picks)``. Under greedy decoding the engine copies only the
+    picks to the host and leaves the logits on the device.
 
     ``decode_many`` and ``insert`` donate the state's caches: the state
     passed in is spent, and the caller keeps the one returned.
@@ -117,15 +122,20 @@ def make_serving_fns(cfg: ModelConfig, cap: int, layout: str = "dense",
         caches = _pad_attn_caches(caches, cfg, cap)
         logits = unembed(params, cfg,
                          jnp.take(hidden[0], true_len - 1, axis=0))
+        picks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         if want_hidden:
-            return logits, caches, out[3]
-        return logits, caches
+            return logits, caches, out[3][0], picks
+        return logits, caches, picks
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode_many(params, caches, rest, tokens):
-        return decode_step(params, cfg, {**rest, "caches": caches},
-                           {"tokens": tokens}, return_prenorm=want_hidden,
-                           paged_kernel=paged_kernel)
+        out = decode_step(params, cfg, {**rest, "caches": caches},
+                          {"tokens": tokens}, return_prenorm=want_hidden,
+                          paged_kernel=paged_kernel)
+        picks = jnp.argmax(out[0], axis=-1).astype(jnp.int32)
+        if want_hidden:
+            return out[0], out[1], out[2][:, 0], picks
+        return out[0], out[1], picks
 
     if layout == "dense":
         @functools.partial(jax.jit, donate_argnums=(0,))
@@ -205,6 +215,11 @@ class ServingEngine:
         self._c_req = obs.counter_group("serve.requests")
         for k in ("submitted", "done", "rejected", "dropped", "deferred"):
             self._c_req.inc(k, 0)       # declare: dump shows explicit zeros
+        # served tokens by where their choice was made: the program's greedy
+        # pick ("device") or a draw from a logits row on the host ("host")
+        self._c_picks = obs.counter_group("serve.picks")
+        for k in ("device", "host"):
+            self._c_picks.inc(k, 0)
         self.decode_steps = 0
         self.temperature = float(temperature)
         self.top_p = float(top_p)
@@ -407,11 +422,29 @@ class ServingEngine:
         arr = np.asarray(self._recent_steps)
         return tuple(float(np.percentile(arr, q)) for q in qs)
 
-    # -- host-side sampling --------------------------------------------------
-    def _pick_token(self, req: Request, logits_row: np.ndarray) -> int:
+    # -- picking tokens -------------------------------------------------------
+    def _rows(self, out) -> np.ndarray:
+        """What the host reads of a prefill or decode program's output
+        ``out`` to pick tokens, one row per sequence: under greedy decoding
+        the program's picks, each a length-1 row; else the logits rows.
+        The rest stays on the device."""
         if self.temperature <= 0:
-            return int(np.argmax(logits_row))
-        p = spec.adjust_probs(logits_row, self.temperature, self.top_p)
+            return np.asarray(out[-1]).reshape(-1, 1)
+        logits = np.asarray(out[0])
+        return logits.reshape(-1, logits.shape[-1])
+
+    def _pick_token(self, req: Request, row: np.ndarray) -> int:
+        """The next token of ``req`` from its ``row`` of :meth:`_rows`.
+
+        Under greedy decoding ``row`` is the program's choice, a length-1
+        int array, and is served as it is: the (slots, V) logits never
+        leave the device. With ``temperature > 0`` it is the logits row,
+        and ``req``'s Philox chain draws from it on the host."""
+        if self.temperature <= 0:
+            self._c_picks.inc("device")
+            return int(row[0])
+        self._c_picks.inc("host")
+        p = spec.adjust_probs(row, self.temperature, self.top_p)
         rng = spec.philox(self.seed, req.sample_key, req.n_draws)
         req.n_draws += 1
         return int(rng.choice(len(p), p=p))
@@ -470,14 +503,13 @@ class ServingEngine:
                           queue_wait_ms=round(wait_ms, 3)):
                 out = self._prefill(self.params, jnp.asarray(toks),
                                     jnp.asarray(req.true_len))
-                logits, caches = out[0], out[1]
                 self.state = self._insert(
-                    self._sync_state(self.state), caches,
+                    self._sync_state(self.state), out[1],
                     jnp.asarray(req.true_len, jnp.int32),
                     jnp.asarray(slot, jnp.int32))
                 self.pos_host[slot] = req.true_len
                 if self.keep_residual:
-                    h = np.asarray(out[2][0], np.float32)
+                    h = np.asarray(out[2], np.float32)
                     self.resid[slot, :req.true_len] = h[:req.true_len]
                     self.resid_from[slot] = 0
                 if self.d_cfg is not None:
@@ -487,7 +519,7 @@ class ServingEngine:
                         self._sync_state(self.d_state), d_out[1],
                         jnp.asarray(req.true_len, jnp.int32),
                         jnp.asarray(slot, jnp.int32))
-                req.tokens.append(self._pick_token(req, np.asarray(logits)))
+                req.tokens.append(self._pick_token(req, self._rows(out)[0]))
                 req.t_first = time.perf_counter()
             self._h_ttft.observe((req.t_first - req.t_submit) * 1e3)
             req.status, req.slot = "running", slot
@@ -552,19 +584,19 @@ class ServingEngine:
         state = self._sync_state(self.state)
         with obs.span("serve.decode", **self._round_attrs(active, 1)) as sp:
             out = self._decode(self.params, state, jnp.asarray(last))
-            logits = out[0]
-            logits.block_until_ready()
+            out[-1].block_until_ready()
         self._observe_step(sp.dur_ms)
         self.decode_steps += 1
         self.state = out[1]
-        with obs.span("serve.sample", active=len(active)):
-            L = np.asarray(logits)
+        with obs.span("serve.sample", active=len(active),
+                      picked="device" if self.temperature <= 0 else "host"):
+            rows = self._rows(out)
             if self.keep_residual:
-                h = np.asarray(out[2][:, 0], np.float32)
+                h = np.asarray(out[2], np.float32)
             for i, r in active:
                 if self.keep_residual:
                     self.resid[i, self.pos_host[i]] = h[i]
-                r.tokens.append(self._pick_token(r, L[i]))
+                r.tokens.append(self._pick_token(r, rows[i]))
                 self._finish_if_done(r)
 
     def _spec_round(self, active) -> None:

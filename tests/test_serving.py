@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs.paper_models import BERT_SMALL
 from repro.core import apply_ligo, init_ligo_params
 from repro.core.grow_cache import (CacheGrowthError, can_grow_cache,
@@ -45,10 +46,9 @@ def small_params():
 
 
 def _fill_engine(params, cfg, *, n_req=4, gen=12, mesh=None, slots=2,
-                 queue_capacity=64):
-    eng = ServingEngine(params, cfg, slots=slots, prompt_budget=8,
-                        gen_budget=gen, queue_capacity=queue_capacity,
-                        mesh=mesh)
+                 queue_capacity=64, cls=ServingEngine, **engine_kw):
+    eng = cls(params, cfg, slots=slots, prompt_budget=8, gen_budget=gen,
+              queue_capacity=queue_capacity, mesh=mesh, **engine_kw)
     rng = np.random.RandomState(0)
     for i in range(n_req):
         eng.submit(list(rng.randint(0, cfg.vocab_size, 4 + i % 4)),
@@ -152,10 +152,12 @@ def test_cache_migration_matches_reprefill_decode(mesh_factory, small_params,
     toks = jnp.asarray(last)
     sa, sb, ss = migrated, oracle, eng.state
     for _ in range(4):
-        la, sa = decode(big, sa, toks)
-        lb, sb = decode(big, sb, toks)
-        ls, ss = decode_small(small_params, ss, toks)
+        la, sa, pa = decode(big, sa, toks)
+        lb, sb, _ = decode(big, sb, toks)
+        ls, ss, _ = decode_small(small_params, ss, toks)
         la, lb, ls = (np.asarray(x) for x in (la, lb, ls))
+        # the program's greedy pick is the host's argmax of its logits
+        np.testing.assert_array_equal(np.asarray(pa), np.argmax(la, -1))
         if method == "lemon":
             if math.prod(mesh_def[0]) == 1:
                 assert np.array_equal(la[live], ls[live])
@@ -172,8 +174,9 @@ def test_cache_migration_matches_reprefill_decode(mesh_factory, small_params,
 # ---------------------------------------------------------------------------
 def _run_with_hop(params, cfg2, op, *, fail_at=None, retries=2,
                   background=False, timeout=120.0, hop_at=2, gen=16,
-                  cache_mode="auto", mesh=None):
-    eng = _fill_engine(params, TINY, n_req=4, gen=gen, mesh=mesh)
+                  cache_mode="auto", mesh=None, **engine_kw):
+    eng = _fill_engine(params, TINY, n_req=4, gen=gen, mesh=mesh,
+                       **engine_kw)
     hop = HopController(eng, cfg2, op, cache_mode=cache_mode,
                         fail_at=fail_at, retries=retries, backoff=0.01,
                         timeout=timeout, background=background)
@@ -225,6 +228,49 @@ def test_hop_chaos_rolls_back_and_retry_succeeds(small_params, stage):
     c = eng.counts()
     assert c["done"] == 4 and c["dropped"] == 0, (stage, c)
     assert all(len(r.tokens) == r.max_new for r in eng.requests)
+
+
+class _HostPicks(ServingEngine):
+    """The oracle for the program's picks: every logits row goes to the
+    host, and greedy decoding takes NumPy's argmax of it there."""
+
+    def _rows(self, out):
+        logits = np.asarray(out[0])
+        return logits.reshape(-1, logits.shape[-1])
+
+    def _pick_token(self, req, row):
+        if self.temperature <= 0:
+            return int(np.argmax(row))
+        return super()._pick_token(req, row)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.9], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("kv_layout", ["paged", "dense"])
+def test_picks_match_the_host_path_across_a_hop(small_params, kv_layout,
+                                                temperature):
+    """Through a LiGO hop (re-prefill migration), the engine serves the
+    tokens of the host path: greedy, the program's pick is the host's
+    argmax, and every token counts under ``serve.picks["device"]``;
+    sampled, the logits rows still reach the host, the Philox stream is
+    the same, and every token counts under ``"host"``."""
+    from repro.obs import FLIGHT
+    op = _operator("ligo", BIG)
+    kw = dict(kv_layout=kv_layout, temperature=temperature, seed=3)
+    oracle, _ = _run_with_hop(small_params, BIG, op, cls=_HostPicks, **kw)
+    picks = obs.counter_group("serve.picks")
+    before = {k: picks[k] for k in ("device", "host")}
+    eng, hop = _run_with_hop(small_params, BIG, op, **kw)
+    assert hop.completed and hop.cache_path == "reprefill"
+    assert eng.kv_layout == kv_layout
+    assert all(r.status == "done" for r in eng.requests)
+    assert ([r.tokens for r in eng.requests]
+            == [r.tokens for r in oracle.requests])
+    served = sum(len(r.tokens) for r in eng.requests)
+    where = "device" if temperature <= 0 else "host"
+    assert {k: picks[k] - before[k] for k in before} == {
+        k: served if k == where else 0 for k in before}
+    sample = FLIGHT.events(type="span", prefix="serve.sample")[-1]
+    assert sample["attrs"]["picked"] == where
 
 
 def test_hop_gives_up_and_engine_survives_on_old_weights(small_params):

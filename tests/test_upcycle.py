@@ -284,10 +284,12 @@ def test_inplace_migration_matches_reprefill(mesh_factory, dense_params,
     toks = jnp.asarray(last)
     sa, sb, ss = migrated, oracle, eng.state
     for _ in range(4):
-        la, sa = decode(big, sa, toks)
-        lb, sb = decode(big, sb, toks)
-        ls, ss = decode_small(dense_params, ss, toks)
+        la, sa, pa = decode(big, sa, toks)
+        lb, sb, _ = decode(big, sb, toks)
+        ls, ss, _ = decode_small(dense_params, ss, toks)
         la, lb, ls = (np.asarray(x) for x in (la, lb, ls))
+        # the program's greedy pick is the host's argmax of its logits
+        np.testing.assert_array_equal(np.asarray(pa), np.argmax(la, -1))
         if math.prod(mesh_def[0]) == 1:
             assert np.array_equal(la[live], ls[live])
         else:
