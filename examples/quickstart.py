@@ -77,8 +77,7 @@ def main():
     for name, p in inits.items():
         _, l = train(BIG, p, 100)
         print(f"   {name:10s} {l:.3f}")
-    print("LiGO should start (and stay) ahead — see benchmarks/ for the "
-          "full savings curves.")
+    print("LiGO should start (and stay) ahead.")
 
 
 if __name__ == "__main__":

@@ -80,14 +80,13 @@ def ligo_remat_needed(cfg2: ModelConfig, batch_shape,
 
 
 def ligo_loss(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
-              batch, *, loss_chunk: int = 0, engine: str = "plan"
-              ) -> jax.Array:
+              batch, *, loss_chunk: int = 0) -> jax.Array:
     """The task loss of the grown model; its backward recomputes the grown
     layers where :func:`ligo_remat_needed` says so from the shapes."""
     x = next((batch[k] for k in ("tokens", "frames", "patches")
               if k in batch), None)           # (batch, positions, ...)
     remat = x is not None and ligo_remat_needed(cfg2, x.shape)
-    big = apply_ligo(ligo, small_params, cfg1, cfg2, engine=engine)
+    big = apply_ligo(ligo, small_params, cfg1, cfg2)
     loss, _ = loss_fn(big, cfg2, batch, loss_chunk=loss_chunk, remat=remat)
     return loss
 
@@ -112,9 +111,7 @@ def _ligo_phase_id(cfg1: ModelConfig, cfg2: ModelConfig, steps: int,
 def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
                data_it: Iterator[Dict[str, jax.Array]], *,
                steps: int = 100, lr: float = 1e-3, momentum: float = 0.9,
-               loss_chunk: int = 0, jit: bool = True,
-               log_every: int = 0, engine: str = "plan",
-               scan_chunk: int = 0, phase_ckpt=None,
+               loss_chunk: int = 0, scan_chunk: int = 0, phase_ckpt=None,
                phase_meta: Optional[Dict] = None,
                checkpoint_every_chunks: int = 1,
                fail_at: Optional[int] = None,
@@ -166,8 +163,7 @@ def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
     from the trajectory runner.
     """
     grad_fn = jax.value_and_grad(
-        partial(ligo_loss, cfg1=cfg1, cfg2=cfg2, loss_chunk=loss_chunk,
-                engine=engine),
+        partial(ligo_loss, cfg1=cfg1, cfg2=cfg2, loss_chunk=loss_chunk),
         argnums=0)
 
     def sgd_step(small, carry, batch):
@@ -220,16 +216,15 @@ def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
                   flush=True)
             obs.event("ligo.resume", step=start, steps=steps)
 
-    if jit:
-        # Donating the (ligo, momentum) carry keeps the phase zero-copy
-        # between chunks; CPU jax warns on donation, so gate it. The first
-        # chunk would otherwise donate (delete) the *caller's* operator
-        # buffers, so hand it an owned copy.
-        donate = (0, 1) if jax.default_backend() != "cpu" else ()
-        run_chunk = jax.jit(run_chunk, donate_argnums=donate)
-        if donate:
-            ligo = jax.tree.map(jnp.array, ligo)
-            mom = jax.tree.map(jnp.array, mom)
+    # Donating the (ligo, momentum) carry keeps the phase zero-copy between
+    # chunks; CPU jax warns on donation, so gate it. The first chunk would
+    # otherwise donate (delete) the *caller's* operator buffers, so hand it
+    # an owned copy.
+    donate = (0, 1) if jax.default_backend() != "cpu" else ()
+    run_chunk = jax.jit(run_chunk, donate_argnums=donate)
+    if donate:
+        ligo = jax.tree.map(jnp.array, ligo)
+        mom = jax.tree.map(jnp.array, mom)
 
     peek = None
     for _ in range(start):          # deterministic resume: skip spent batches
@@ -243,7 +238,7 @@ def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
     led_state = {"tokens": None, "fps_model": None, "meas_fps": None}
 
     def _ledger_prepare(batch_tree, n_chunk: int) -> None:
-        """Model + (when jitted) measure the chunk program, once per phase.
+        """Model + measure the chunk program, once per phase.
         ``batch_tree`` is one un-stacked batch; lowering only needs shapes,
         so the resume path reuses a discarded batch as the witness."""
         from repro.roofline import train_flops_per_step
@@ -254,7 +249,7 @@ def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
         bsz, seq = int(leaf.shape[0]), int(leaf.shape[1])
         led_state["tokens"] = float(bsz * seq)
         led_state["fps_model"] = train_flops_per_step(cfg2, bsz, seq)
-        if jit and n_chunk > 0:
+        if n_chunk > 0:
             from repro.obs import costs
             stacked = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct((n_chunk,) + x.shape,
@@ -336,10 +331,6 @@ def train_ligo(ligo, small_params, cfg1: ModelConfig, cfg2: ModelConfig,
                 phase_ckpt.wait()          # the injected kill must be durable
             raise RuntimeError(
                 f"injected LiGO-phase failure at step {done}/{steps}")
-        if log_every:
-            for s in range(done - n, done):
-                if s % log_every == 0:
-                    print(f"[ligo] step {s:4d} loss {losses[s]:.4f}")
     if phase_ckpt is not None:
         phase_ckpt.wait()
     return ligo, losses
@@ -385,7 +376,7 @@ def grow(small_params, cfg1: ModelConfig, cfg2: ModelConfig, *,
          data_it: Optional[Iterator] = None, ligo_steps: int = 100,
          ligo_lr: float = 1e-3, ligo_momentum: float = 0.9,
          loss_chunk: int = 0, depth_init: str = "stack",
-         engine: str = "plan", opt_state=None, grow_optimizer: bool = True,
+         opt_state=None, grow_optimizer: bool = True,
          apply: bool = True, ligo_ckpt=None,
          ligo_meta: Optional[Dict] = None, ligo_scan_chunk: int = 0,
          ligo_fail_at: Optional[int] = None,
@@ -457,7 +448,7 @@ def grow(small_params, cfg1: ModelConfig, cfg2: ModelConfig, *,
                     op, losses = train_ligo(
                         op, small_params, cfg1, cfg2, data_it,
                         steps=ligo_steps, lr=ligo_lr, momentum=ligo_momentum,
-                        loss_chunk=loss_chunk, engine=engine,
+                        loss_chunk=loss_chunk,
                         scan_chunk=ligo_scan_chunk, phase_ckpt=ligo_ckpt,
                         phase_meta=ligo_meta, fail_at=ligo_fail_at,
                         ledger=ligo_ledger, ledger_ctx=ligo_ledger_ctx)
@@ -468,13 +459,13 @@ def grow(small_params, cfg1: ModelConfig, cfg2: ModelConfig, *,
         if not apply:
             return None, info
         with obs.span("grow.params"):
-            big = apply_ligo(op, small_params, cfg1, cfg2, engine=engine)
+            big = apply_ligo(op, small_params, cfg1, cfg2)
         if opt_state is not None:
             if grow_optimizer:
                 from repro.optim import grow_adamw_state
                 with obs.span("grow.moments"):
                     info["opt_state"] = grow_adamw_state(
-                        opt_state, op, cfg1, cfg2, engine=engine)
+                        opt_state, op, cfg1, cfg2)
             else:
                 from repro.optim import adamw_init
                 info["opt_state"] = adamw_init(big)

@@ -18,12 +18,12 @@ expanders. Untied in-expanders (needed to express Net2Net's normalised
 duplication exactly, App. A Eq. 12) are supported by storing an override under
 ``"<name>__in"``.
 
-Two execution engines: ``engine="plan"`` (default) compiles the growth once
-per (cfg1, cfg2, tree) into a :class:`repro.core.plan.GrowthPlan` — cached
-expander resolution, leaves batched by (family, shape, expander pair),
-min-FLOP contraction order, fused Pallas blend-expand on TPU;
-``engine="legacy"`` is the original per-leaf walk below, kept as the
-correctness oracle (tests assert plan == legacy for every operator).
+``apply_ligo`` compiles the growth once per (cfg1, cfg2, tree) into a
+:class:`repro.core.plan.GrowthPlan` — cached expander resolution, leaves
+batched by (family, shape, expander pair), min-FLOP contraction order, fused
+Pallas blend-expand on TPU. ``apply_ligo_reference`` is the original per-leaf
+walk, kept only as the correctness oracle (tests assert plan == reference for
+every operator).
 """
 from __future__ import annotations
 
@@ -204,24 +204,21 @@ def count_ligo_params(ligo: Params) -> int:
 # Apply: Θ_large = M(Θ_small)
 # ---------------------------------------------------------------------------
 def apply_ligo(ligo: Params, small: Params, cfg1: ModelConfig,
-               cfg2: ModelConfig, *, engine: str = "plan",
-               use_kernel: Optional[bool] = None, mesh=None,
-               square: bool = False) -> Params:
+               cfg2: ModelConfig, *, use_kernel: Optional[bool] = None,
+               mesh=None, square: bool = False) -> Params:
     """Grow a small model's parameter tree into the large architecture.
 
-    ``engine="plan"`` (default) routes through the compiled
-    :class:`repro.core.plan.GrowthPlan` — expanders resolved once per call,
-    leaves batched by (family, shape, expander pair), fused Pallas
-    blend-expand on TPU. ``engine="legacy"`` keeps the original per-leaf
-    einsum walk as the correctness oracle. ``use_kernel`` forces/disables the
-    fused Pallas path (plan engine only; default: auto — TPU yes, CPU no).
+    Growth runs through the compiled :class:`repro.core.plan.GrowthPlan` —
+    expanders resolved once per call, leaves batched by (family, shape,
+    expander pair), fused Pallas blend-expand on TPU. ``use_kernel``
+    forces/disables the fused Pallas path (default: auto — TPU yes, CPU no).
 
-    ``mesh`` (plan engine only) runs the growth sharded: the executor is
-    pjit-compiled with ``params_pspecs``-derived in/out shardings (expanders
-    replicated, leaf stacks sharded like their model weights) and the fused
-    path runs per shard under ``shard_map``. Default: the ambient mesh
-    installed by ``jax.set_mesh`` when one exists — the train/serve
-    drivers grow distributed without passing anything.
+    ``mesh`` runs the growth sharded: the executor is pjit-compiled with
+    ``params_pspecs``-derived in/out shardings (expanders replicated, leaf
+    stacks sharded like their model weights) and the fused path runs per
+    shard under ``shard_map``. Default: the ambient mesh installed by
+    ``jax.set_mesh`` when one exists — the train/serve drivers grow
+    distributed without passing anything.
 
     ``square=True`` applies the *elementwise-squared* operator: every
     resolved leaf expander and depth blend is squared after resolution
@@ -230,16 +227,21 @@ def apply_ligo(ligo: Params, small: Params, cfg1: ModelConfig,
     then under the independent-gradient approximation ``v_large = Σ cᵢ² vᵢ``
     — see :func:`repro.optim.grow_adamw_state`.
     """
-    if engine in ("plan", "auto"):
-        from repro.core.plan import plan_for
-        if mesh is None:
-            from repro.distributed.sharding import current_mesh
-            mesh = current_mesh()
-        plan = plan_for(cfg1, cfg2, small)
-        return plan.executor(use_kernel=use_kernel, mesh=mesh,
-                             square=square)(ligo, small)
-    if engine != "legacy":
-        raise ValueError(f"unknown growth engine {engine!r}")
+    from repro.core.plan import plan_for
+    if mesh is None:
+        from repro.distributed.sharding import current_mesh
+        mesh = current_mesh()
+    plan = plan_for(cfg1, cfg2, small)
+    return plan.executor(use_kernel=use_kernel, mesh=mesh,
+                         square=square)(ligo, small)
+
+
+def apply_ligo_reference(ligo: Params, small: Params, cfg1: ModelConfig,
+                         cfg2: ModelConfig, *, square: bool = False
+                         ) -> Params:
+    """The per-leaf einsum walk of :func:`apply_ligo`'s operator: the
+    correctness oracle that tests compare the growth plan against. No
+    production path calls it. ``square`` is as in :func:`apply_ligo`."""
     width = ligo["width"]
     top = S.top_spec()
     out_layers: Params = {}

@@ -1,10 +1,11 @@
 """GrowthPlan: a compiled, fused growth engine for ``apply_ligo``.
 
-The legacy ``apply_ligo`` walks the parameter tree leaf by leaf, re-resolving
-every expander expression (``gamma`` block-repeats, ``seg`` block-diagonals)
-per leaf per call and emitting per-leaf einsums. That is the hot path of the
-whole reproduction: it runs — and is differentiated through — on every one of
-the ~100 LiGO SGD steps, and again for the final materialisation.
+The per-leaf walk (``apply_ligo_reference``) goes through the parameter tree
+leaf by leaf, re-resolving every expander expression (``gamma``
+block-repeats, ``seg`` block-diagonals) per leaf per call and emitting
+per-leaf einsums. Growth is the hot path of the whole reproduction: it
+runs — and is differentiated through — on every one of the ~100 LiGO SGD
+steps, and again for the final materialisation.
 
 A :class:`GrowthPlan` is compiled **once** per ``(cfg1, cfg2, tree shape)``
 and fixes, ahead of time:
@@ -84,8 +85,9 @@ squared hops for dense or GQA-``gamma`` factors (elementwise ``(B·A)²``
 carries cross terms that ``B²·A²`` does not) — grow AdamW ``v`` per hop
 when that distinction matters (see the ROADMAP open item).
 
-The legacy path survives as ``apply_ligo(..., engine="legacy")`` — the
-correctness oracle every plan output is tested against.
+The per-leaf walk survives as :func:`repro.core.ligo.apply_ligo_reference`
+— the correctness oracle every plan output is tested against; no production
+path calls it.
 """
 from __future__ import annotations
 
@@ -248,8 +250,8 @@ class GrowthPlan:
 
     Built once per ``(cfg1, cfg2, parameter-tree signature)`` via
     :func:`plan_for`; ``apply`` is a pure, differentiable function of
-    ``(ligo_params, small_params)`` with identical semantics to the legacy
-    ``apply_ligo`` walk.
+    ``(ligo_params, small_params)`` with identical semantics to the
+    per-leaf walk ``apply_ligo_reference``.
     """
 
     def __init__(self, cfg1: ModelConfig, cfg2: ModelConfig,

@@ -163,9 +163,9 @@ ALLOWED_FAMILY_HOPS = (("dense", "moe"),)
 def family_hop(cfg1: ModelConfig, cfg2: ModelConfig) -> Optional[Dict]:
     """Structural map of a family-changing hop, or None for same-family.
 
-    A hop descriptor tells both growth engines (the legacy walk and the
-    compiled :class:`repro.core.plan.GrowthPlan`) how source layer stacks
-    land in the target architecture:
+    A hop descriptor tells the compiled :class:`repro.core.plan.GrowthPlan`
+    (and the reference walk ``apply_ligo_reference``) how source layer
+    stacks land in the target architecture:
 
     - ``kind_map``:  source stack kind → target stack kind
     - ``renames``:   source leaf path → target leaf path within the stack

@@ -55,10 +55,8 @@ What the layer costs is measured on one TPU v5e, against the same code
 without these spans and on the same seeds: the LiGO hop's ``hop_s`` moved
 +0.46% and serving's inter-token p95 -0.05% (medians of two runs, inside
 the run-to-run spread); a profiler session on top moved ``hop_s`` by
--1.6% and +1.6% and the inter-token p95 by -0.4% and +0.2%. An annotation costs about a microsecond outside a
-profiler session. The CPU
-``obs_overhead`` ratio in ``BENCH_growth.json`` gates nothing the product
-runs on; retiring it is ROADMAP D7's.
+-1.6% and +1.6% and the inter-token p95 by -0.4% and +0.2%. An
+annotation costs about a microsecond outside a profiler session.
 """
 from repro.obs.metrics import (
     Counter, CounterGroup, Gauge, Histogram, LOG10_BUCKETS, MetricsRegistry,

@@ -55,7 +55,6 @@ def hop_uses_grouped_gamma(cfg1, cfg2) -> bool:
 
 
 def grow_adamw_state(state: AdamWState, op, cfg1, cfg2, *,
-                     engine: str = "plan",
                      use_kernel: Optional[bool] = None,
                      mesh=None) -> AdamWState:
     """Map an AdamW state through a growth operator (see module docstring).
@@ -67,16 +66,14 @@ def grow_adamw_state(state: AdamWState, op, cfg1, cfg2, *,
     grown moments exactly where the train step wants them.
     """
     from repro.core.ligo import apply_ligo
-    m = apply_ligo(op, state.m, cfg1, cfg2, engine=engine,
-                   use_kernel=use_kernel, mesh=mesh)
-    v = apply_ligo(op, state.v, cfg1, cfg2, engine=engine,
-                   use_kernel=use_kernel, mesh=mesh, square=True)
+    m = apply_ligo(op, state.m, cfg1, cfg2, use_kernel=use_kernel, mesh=mesh)
+    v = apply_ligo(op, state.v, cfg1, cfg2, use_kernel=use_kernel, mesh=mesh,
+                   square=True)
     return AdamWState(m=m, v=v, count=state.count)
 
 
 def grow_adamw_state_chain(state: AdamWState, ops: Sequence, cfgs: Sequence,
-                           *, engine: str = "plan",
-                           use_kernel: Optional[bool] = None,
+                           *, use_kernel: Optional[bool] = None,
                            mesh=None) -> AdamWState:
     """Map an AdamW state through a *chain* of growth operators
     (``ops[i]: cfgs[i] → cfgs[i+1]``) — the skip-stage restart path.
@@ -100,19 +97,18 @@ def grow_adamw_state_chain(state: AdamWState, ops: Sequence, cfgs: Sequence,
                          f"configs, got {len(cfgs)}")
     if len(ops) == 1:
         return grow_adamw_state(state, ops[0], cfgs[0], cfgs[1],
-                                engine=engine, use_kernel=use_kernel,
-                                mesh=mesh)
+                                use_kernel=use_kernel, mesh=mesh)
     composed = compose_chain(list(ops), list(cfgs))
-    m = apply_ligo(composed, state.m, cfgs[0], cfgs[-1], engine=engine,
+    m = apply_ligo(composed, state.m, cfgs[0], cfgs[-1],
                    use_kernel=use_kernel, mesh=mesh)
     per_hop_v = any(hop_uses_grouped_gamma(a, b)
                     for a, b in zip(cfgs[:-1], cfgs[1:]))
     if per_hop_v:
         v = state.v
         for op, a, b in zip(ops, cfgs[:-1], cfgs[1:]):
-            v = apply_ligo(op, v, a, b, engine=engine,
-                           use_kernel=use_kernel, mesh=mesh, square=True)
+            v = apply_ligo(op, v, a, b, use_kernel=use_kernel, mesh=mesh,
+                           square=True)
     else:
-        v = apply_ligo(composed, state.v, cfgs[0], cfgs[-1], engine=engine,
+        v = apply_ligo(composed, state.v, cfgs[0], cfgs[-1],
                        use_kernel=use_kernel, mesh=mesh, square=True)
     return AdamWState(m=m, v=v, count=state.count)

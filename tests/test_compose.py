@@ -11,8 +11,8 @@ import pytest
 from conftest import assert_trees_close_normalized
 
 from repro.configs.paper_models import BERT_SMALL
-from repro.core import (apply_ligo, compose_chain, compose_ligo,
-                        init_ligo_params, plan_for)
+from repro.core import (apply_ligo, apply_ligo_reference, compose_chain,
+                        compose_ligo, init_ligo_params, plan_for)
 from repro.core import operators as ops
 from repro.models import init_params
 
@@ -70,8 +70,8 @@ def test_composed_plan_matches_sequential(method):
     op_a = _operator(method, jax.random.PRNGKey(1), c1, c2)
     op_b = _operator(method, jax.random.PRNGKey(2), c2, c3)
 
-    mid = apply_ligo(op_a, sp, c1, c2, engine="legacy")
-    want = apply_ligo(op_b, mid, c2, c3, engine="legacy")
+    mid = apply_ligo_reference(op_a, sp, c1, c2)
+    want = apply_ligo_reference(op_b, mid, c2, c3)
 
     composed = compose_ligo(op_a, op_b, c1, c2, c3)
     got = plan_for(c1, c3, sp).executor()(composed, sp)
@@ -91,7 +91,7 @@ def test_compose_chain_three_hops_and_identity():
 
     cur = sp
     for op, a, b in zip(op_list, chain[:-1], chain[1:]):
-        cur = apply_ligo(op, cur, a, b, engine="legacy")
+        cur = apply_ligo_reference(op, cur, a, b)
     composed = compose_chain(op_list, chain)
     got = apply_ligo(composed, sp, C1, c4)
     assert_trees_close_normalized(got, cur, rel=2e-6, names=_names(cur))
@@ -117,10 +117,10 @@ def test_compose_squared_operator_consistency():
                lambda a, b, key: ops.bert2bert_operator(key, a, b)):
         op_a = mk(m1, m2, key=jax.random.PRNGKey(1))
         op_b = mk(m2, m3, key=jax.random.PRNGKey(2))
-        mid = apply_ligo(op_a, sp, m1, m2, engine="legacy", square=True)
-        want = apply_ligo(op_b, mid, m2, m3, engine="legacy", square=True)
+        mid = apply_ligo_reference(op_a, sp, m1, m2, square=True)
+        want = apply_ligo_reference(op_b, mid, m2, m3, square=True)
         composed = compose_ligo(op_a, op_b, m1, m2, m3)
-        got = apply_ligo(composed, sp, m1, m3, engine="legacy", square=True)
+        got = apply_ligo_reference(composed, sp, m1, m3, square=True)
         assert_trees_close_normalized(got, want, rel=1e-5,
                                       names=_names(want))
 
@@ -185,10 +185,10 @@ def test_compose_property_random_triples():
             sp = f64(init_params(c1, jax.random.PRNGKey(0)))
             op_a = f64(_operator(method, jax.random.PRNGKey(1), c1, c2))
             op_b = f64(_operator(method, jax.random.PRNGKey(2), c2, c3))
-            mid = apply_ligo(op_a, sp, c1, c2, engine="legacy")
-            want = apply_ligo(op_b, mid, c2, c3, engine="legacy")
-            got = apply_ligo(compose_ligo(op_a, op_b, c1, c2, c3), sp,
-                             c1, c3, engine="legacy")
+            mid = apply_ligo_reference(op_a, sp, c1, c2)
+            want = apply_ligo_reference(op_b, mid, c2, c3)
+            got = apply_ligo_reference(
+                compose_ligo(op_a, op_b, c1, c2, c3), sp, c1, c3)
             assert_trees_close_normalized(got, want, rel=1e-6,
                                           names=_names(want))
 

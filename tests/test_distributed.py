@@ -14,7 +14,8 @@ def test_sharded_growth_end_to_end(subproc):
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.paper_models import BERT_SMALL
-from repro.core import apply_ligo, init_ligo_params, plan_for, train_ligo
+from repro.core import (apply_ligo, apply_ligo_reference, init_ligo_params,
+                        plan_for, train_ligo)
 from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.models.inputs import dummy_batch
@@ -27,7 +28,7 @@ c2 = c1.scaled(name="sg2", n_layers=4, d_model=64, n_heads=8, n_kv_heads=8,
 sp = init_params(c1, jax.random.PRNGKey(0))
 lg = init_ligo_params(jax.random.PRNGKey(1), c1, c2)
 mesh = make_mesh((2, 4), ("data", "model"))
-legacy = apply_ligo(lg, sp, c1, c2, engine="legacy")
+legacy = apply_ligo_reference(lg, sp, c1, c2)
 with jax.set_mesh(mesh):
     big = apply_ligo(lg, sp, c1, c2)          # ambient mesh -> sharded plan
 for a, b in zip(jax.tree.leaves(legacy), jax.tree.leaves(big)):

@@ -1,17 +1,20 @@
-"""GrowthPlan engine: plan/fused output == legacy apply_ligo for every grow
-method, custom_vjp gradients == einsum-reference gradients (fused Pallas
+"""GrowthPlan engine: plan/fused output == apply_ligo_reference for every
+grow method, custom_vjp gradients == einsum-reference gradients (fused Pallas
 fwd+bwd kernels in interpret mode), one kernel launch per leaf group,
 universal eligibility (4-D MoE stacks, non-128-aligned dims), single-trace
 LiGO phase, and once-per-apply expander resolution."""
+import tempfile
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, grow_target, smoke_config
 from repro.configs.paper_models import BERT_SMALL
-from repro.core import (TRACE_COUNTS, apply_ligo, init_ligo_params, plan_for,
-                        train_ligo)
+from repro.core import (TRACE_COUNTS, apply_ligo, apply_ligo_reference,
+                        init_ligo_params, plan_for, train_ligo)
 from repro.core import operators as ops
 from repro.core.plan import RESOLVE_COUNTS
 from repro.kernels import (LAUNCH_COUNTS, ligo_blend_expand_ref,
@@ -52,8 +55,8 @@ METHODS = ("ligo", "stackbert", "interpolation", "net2net", "bert2bert")
 @pytest.mark.parametrize("method", METHODS)
 def test_plan_matches_legacy(small_params, method):
     op = _operator(method)
-    legacy = apply_ligo(op, small_params, CFG1, CFG2, engine="legacy")
-    plan = apply_ligo(op, small_params, CFG1, CFG2, engine="plan")
+    legacy = apply_ligo_reference(op, small_params, CFG1, CFG2)
+    plan = apply_ligo(op, small_params, CFG1, CFG2)
     assert jax.tree.structure(legacy) == jax.tree.structure(plan)
     for a, b in zip(jax.tree.leaves(legacy), jax.tree.leaves(plan)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -64,7 +67,7 @@ def test_fused_kernel_path_matches_legacy(small_params):
     """use_kernel=True routes eligible groups through the Pallas custom_vjp
     (interpret mode on CPU) — output must still match the legacy walk."""
     op = _operator("ligo")
-    legacy = apply_ligo(op, small_params, CFG1, CFG2, engine="legacy")
+    legacy = apply_ligo_reference(op, small_params, CFG1, CFG2)
     plan = plan_for(CFG1, CFG2, small_params)
     assert any(g.kernel_ok for g in plan.groups), \
         "no fused-eligible groups on the attn family"
@@ -90,8 +93,8 @@ def test_plan_gradients_match_legacy(small_params):
     op = _operator("ligo")
     plan = plan_for(CFG1, CFG2, small_params)
 
-    g_legacy = jax.grad(lambda l: _loss(l, lambda l: apply_ligo(
-        l, small_params, CFG1, CFG2, engine="legacy")))(op)
+    g_legacy = jax.grad(lambda l: _loss(l, lambda l: apply_ligo_reference(
+        l, small_params, CFG1, CFG2)))(op)
     for use_kernel in (False, True):
         g_plan = jax.grad(lambda l: _loss(l, lambda l: plan.apply(
             l, small_params, use_kernel=use_kernel)))(op)
@@ -103,12 +106,12 @@ def test_plan_gradients_match_legacy(small_params):
 @pytest.mark.parametrize("method", METHODS)
 def test_fused_bwd_matches_legacy_grad_all_methods(small_params, method):
     """jax.grad through the fused Pallas fwd+bwd kernels (interpret mode)
-    == jax.grad of engine="legacy" to ≤ 1e-5 relative error, for every
+    == jax.grad of apply_ligo_reference to ≤ 1e-5 relative error, for every
     growth method's operator tree."""
     op = _operator(method)
     plan = plan_for(CFG1, CFG2, small_params)
-    g_legacy = jax.grad(lambda l: _loss(l, lambda l: apply_ligo(
-        l, small_params, CFG1, CFG2, engine="legacy")))(op)
+    g_legacy = jax.grad(lambda l: _loss(l, lambda l: apply_ligo_reference(
+        l, small_params, CFG1, CFG2)))(op)
     g_fused = jax.grad(lambda l: _loss(l, lambda l: plan.apply(
         l, small_params, use_kernel=True)))(op)
     _assert_grads_close(g_legacy, g_fused, rel=1e-5)
@@ -170,14 +173,14 @@ def test_fused_path_universal_coverage(pair):
         assert any(g.kernel_ok and len(g.shape) == 4 for g in plan.groups), \
             "4-D MoE expert stacks must be fused-eligible"
 
-    legacy = apply_ligo(lg, sp, c1, c2, engine="legacy")
+    legacy = apply_ligo_reference(lg, sp, c1, c2)
     fused = plan.apply(lg, sp, use_kernel=True)
     for a, b in zip(jax.tree.leaves(legacy), jax.tree.leaves(fused)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-5)
 
-    g_legacy = jax.grad(lambda l: _loss(l, lambda l: apply_ligo(
-        l, sp, c1, c2, engine="legacy")))(lg)
+    g_legacy = jax.grad(lambda l: _loss(l, lambda l: apply_ligo_reference(
+        l, sp, c1, c2)))(lg)
     g_fused = jax.grad(lambda l: _loss(l, lambda l: plan.apply(
         l, sp, use_kernel=True)))(lg)
     _assert_grads_close(g_legacy, g_fused, rel=1e-5)
@@ -252,7 +255,9 @@ def test_train_ligo_traces_once_and_resolves_once():
 
 
 def test_train_ligo_scan_matches_unchunked():
-    """Chunked scan == one-shot scan (same numerics, carry donation safe)."""
+    """Chunked scan == one-shot scan (same numerics, carry donation safe),
+    also with the carry checkpointed at every chunk boundary (the elastic
+    phase)."""
     cfg2 = CFG1.scaled(name="gp2u", n_layers=4)
     sp = init_params(CFG1, jax.random.PRNGKey(0))
     lg = init_ligo_params(jax.random.PRNGKey(1), CFG1, cfg2)
@@ -265,6 +270,12 @@ def test_train_ligo_scan_matches_unchunked():
     lg_a, loss_a = train_ligo(lg, sp, CFG1, cfg2, batches(), steps=4,
                               scan_chunk=2)
     lg_b, loss_b = train_ligo(lg, sp, CFG1, cfg2, batches(), steps=4)
-    np.testing.assert_allclose(loss_a, loss_b, rtol=1e-6)
-    for a, b in zip(jax.tree.leaves(lg_a), jax.tree.leaves(lg_b)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+    with tempfile.TemporaryDirectory() as d:
+        lg_c, loss_c = train_ligo(lg, sp, CFG1, cfg2, batches(), steps=4,
+                                  scan_chunk=2,
+                                  phase_ckpt=CheckpointManager(d))
+    for lg_x, loss_x in ((lg_a, loss_a), (lg_c, loss_c)):
+        np.testing.assert_allclose(loss_x, loss_b, rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(lg_x), jax.tree.leaves(lg_b)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-6)

@@ -254,7 +254,7 @@ def test_pool_pressure_defers_but_never_drops():
 
 def test_paged_bytes_per_slot_below_dense_for_mixed_lengths():
     """Mixed-length workload: peak paged bytes/slot strictly under the dense
-    layout's constant max_len row (the BENCH criterion, at test scale)."""
+    layout's constant max_len row."""
     params = init_params(TINY, jax.random.PRNGKey(0))
     eng = ServingEngine(params, TINY, slots=4, prompt_budget=16,
                         gen_budget=16, kv_layout="paged", block_size=4)

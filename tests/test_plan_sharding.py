@@ -20,7 +20,8 @@ import pytest
 from conftest import assert_trees_close_normalized
 from test_growth_plan import CFG1, CFG2, METHODS, _operator
 
-from repro.core import apply_ligo, init_ligo_params, plan_for
+from repro.core import (apply_ligo, apply_ligo_reference, init_ligo_params,
+                        plan_for)
 from repro.core.ligo import _flatten
 from repro.distributed.sharding import named_shardings
 from repro.models import init_params
@@ -90,7 +91,7 @@ def test_sharded_fused_path_matches_legacy(mesh_factory, small_params,
     plan = plan_for(CFG1, CFG2, small_params)
     assert any(g.kernel_ok for g in plan.groups)
 
-    legacy = apply_ligo(op, small_params, CFG1, CFG2, engine="legacy")
+    legacy = apply_ligo_reference(op, small_params, CFG1, CFG2)
     fused = plan.apply(op, small_params, use_kernel=True, mesh=mesh)
     for a, b in zip(jax.tree.leaves(legacy), jax.tree.leaves(fused)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -99,8 +100,8 @@ def test_sharded_fused_path_matches_legacy(mesh_factory, small_params,
     def loss(l, fn):
         return sum(jnp.sum(x * x) for x in jax.tree.leaves(fn(l)))
 
-    g_legacy = jax.grad(lambda l: loss(l, lambda l: apply_ligo(
-        l, small_params, CFG1, CFG2, engine="legacy")))(op)
+    g_legacy = jax.grad(lambda l: loss(l, lambda l: apply_ligo_reference(
+        l, small_params, CFG1, CFG2)))(op)
     g_fused = jax.grad(lambda l: loss(l, lambda l: plan.apply(
         l, small_params, use_kernel=True, mesh=mesh)))(op)
     assert_trees_close_normalized(g_fused, g_legacy, rel=1e-5)

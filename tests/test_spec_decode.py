@@ -90,10 +90,13 @@ def test_greedy_spec_bit_equal_to_vanilla(small_params, kv_layout):
 
 def test_lemon_hop_first_round_acceptance_is_total(small_params):
     """A lossless hop means drafter and verifier are the same function:
-    every draft of the first round must be accepted."""
+    every draft of the first round must be accepted, and at least 99% of
+    all drafts through the hop."""
     op = lemon_operator(TINY, WIDE)
     eng, _, _, reqs = _serve(small_params, WIDE, op, spec_k=4)
-    assert eng.spec_stats["first_round_acc"] == 1.0
+    st = eng.spec_stats
+    assert st["first_round_acc"] == 1.0
+    assert st["drafted"] > 0 and st["accepted"] >= 0.99 * st["drafted"]
     assert all(r.status == "done" for r in reqs)
 
 

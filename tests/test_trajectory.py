@@ -16,7 +16,8 @@ from conftest import assert_trees_close_normalized
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import TrainConfig
 from repro.configs.paper_models import BERT_SMALL
-from repro.core import (apply_ligo, compose_chain, grow, init_ligo_params)
+from repro.core import (apply_ligo, apply_ligo_reference, compose_chain,
+                        grow, init_ligo_params)
 from repro.core import operators as cops
 from repro.data import batch_for_step
 from repro.optim import (adamw_init, grow_adamw_state,
@@ -57,13 +58,13 @@ def _pretrained_small(steps=8):
 # ---------------------------------------------------------------------------
 def test_grow_adamw_state_matches_oracle():
     """m maps through the operator, v through the resolve-then-squared
-    operator (legacy-engine oracles), count is preserved and v stays ≥ 0."""
+    operator (reference-walk oracles), count is preserved and v stays ≥ 0."""
     params, opt = _pretrained_small()
     op = init_ligo_params(jax.random.PRNGKey(3), T0, T1)
     grown = grow_adamw_state(opt, op, T0, T1)
 
-    m_ref = apply_ligo(op, opt.m, T0, T1, engine="legacy")
-    v_ref = apply_ligo(op, opt.v, T0, T1, engine="legacy", square=True)
+    m_ref = apply_ligo_reference(op, opt.m, T0, T1)
+    v_ref = apply_ligo_reference(op, opt.v, T0, T1, square=True)
     assert_trees_close_normalized(grown.m, m_ref, rel=1e-5)
     assert_trees_close_normalized(grown.v, v_ref, rel=1e-5)
     assert int(grown.count) == int(opt.count) == 8
@@ -303,12 +304,11 @@ def test_gqa_squared_operators_do_not_compose():
     _, opt = _pretrained(G0)
     op_a = cops.stackbert_operator(G0, G1, key=jax.random.PRNGKey(1))
     op_b = cops.stackbert_operator(G1, G2, key=jax.random.PRNGKey(2))
-    mid = apply_ligo(op_a, opt.v, G0, G1, engine="legacy", square=True)
-    v_seq = apply_ligo(op_b, mid, G1, G2, engine="legacy", square=True)
+    mid = apply_ligo_reference(op_a, opt.v, G0, G1, square=True)
+    v_seq = apply_ligo_reference(op_b, mid, G1, G2, square=True)
     from repro.core import compose_ligo
     composed = compose_ligo(op_a, op_b, G0, G1, G2)
-    v_comp = apply_ligo(composed, opt.v, G0, G2, engine="legacy",
-                        square=True)
+    v_comp = apply_ligo_reference(composed, opt.v, G0, G2, square=True)
     rel = max(float(np.abs(np.asarray(a) - np.asarray(b)).max()
                     / (np.abs(np.asarray(b)).max() + 1e-30))
               for a, b in zip(jax.tree.leaves(v_comp),
@@ -330,8 +330,8 @@ def test_grow_adamw_state_chain_gqa_rule():
     v_ref = opt.v
     m_ref = opt.m
     for op, a, b in zip(ops_list, chain[:-1], chain[1:]):
-        v_ref = apply_ligo(op, v_ref, a, b, engine="legacy", square=True)
-        m_ref = apply_ligo(op, m_ref, a, b, engine="legacy")
+        v_ref = apply_ligo_reference(op, v_ref, a, b, square=True)
+        m_ref = apply_ligo_reference(op, m_ref, a, b)
     assert_trees_close_normalized(grown.v, v_ref, rel=1e-5)
     # m: linear, so composed == sequential — both are the right answer
     assert_trees_close_normalized(grown.m, m_ref, rel=1e-5)
@@ -347,7 +347,7 @@ def test_grow_adamw_state_chain_gqa_rule():
             cops.stackbert_operator(m1, m2, key=jax.random.PRNGKey(2))]
     grown_m = grow_adamw_state_chain(opt_m, mops, [m0, m1, m2])
     comp = compose_chain(mops, [m0, m1, m2])
-    v_comp = apply_ligo(comp, opt_m.v, m0, m2, engine="legacy", square=True)
+    v_comp = apply_ligo_reference(comp, opt_m.v, m0, m2, square=True)
     assert_trees_close_normalized(grown_m.v, v_comp, rel=1e-5)
 
 
@@ -398,7 +398,7 @@ def test_runner_collapses_zero_step_stages_lemon_exact():
     want_m = apply_ligo(comp, opt0.m, G0, G2)
     want_v = opt0.v
     for op, a, b in zip(ops_list, [G0, G1], [G1, G2]):
-        want_v = apply_ligo(op, want_v, a, b, engine="legacy", square=True)
+        want_v = apply_ligo_reference(op, want_v, a, b, square=True)
     assert_trees_close_normalized(snap["params"], want_p, rel=1e-5)
     assert_trees_close_normalized(snap["opt"].m, want_m, rel=1e-5)
     assert_trees_close_normalized(snap["opt"].v, want_v, rel=1e-5)

@@ -1,9 +1,9 @@
 """Cross-family growth: dense→MoE upcycling + the operator zoo around it.
 
 Covers the tentpole (upcycled-MoE function preservation at init, ≤1e-6 on
-logits — in practice bitwise — on plan and legacy engines and on the sharded
-8-virtual-device lane; MHA→GQA head merging vs the grouped-gamma oracle) and
-the satellite fixes: the relaxed GQA lossless-cache gate (in-place migration
+logits — in practice bitwise — through the plan, the reference walk and the
+sharded 8-virtual-device lane; MHA→GQA head merging vs the grouped-gamma
+oracle) and the satellite fixes: the relaxed GQA lossless-cache gate (in-place migration
 vs re-prefill parity on a GQA lemon hop), the config-load-time family gate in
 ``check_growable``, cross-family method gating in ``TrajectoryConfig``, and
 the explicit paged→dense fallback in the serving engine.
@@ -17,7 +17,8 @@ import pytest
 
 from repro.configs import get_config, moe_target, smoke_config
 from repro.configs.paper_models import BERT_SMALL
-from repro.core import apply_ligo, plan_for, place_operator
+from repro.core import (apply_ligo, apply_ligo_reference, plan_for,
+                        place_operator)
 from repro.core import spec as S
 from repro.core.grow_cache import (can_grow_cache, grow_decode_state,
                                    is_lossless_operator)
@@ -60,16 +61,17 @@ def _logits(params, cfg, toks):
 # ---------------------------------------------------------------------------
 # Tentpole: upcycled MoE is the dense model's function at init
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("engine", ["plan", "legacy"])
+@pytest.mark.parametrize("grow_fn", [apply_ligo, apply_ligo_reference],
+                         ids=["plan", "legacy"])
 @pytest.mark.parametrize("cfg2", [MOE, MOE_PAD],
                          ids=["same-ff", "padded-ff"])
-def test_upcycle_function_preserving_at_init(dense_params, engine, cfg2):
+def test_upcycle_function_preserving_at_init(dense_params, grow_fn, cfg2):
     """Expert replication + uniform (zero) router: `apply_moe` renormalises
     the top-k gate weights, so every token gets Σ (1/k)·MLP(x) = MLP(x) —
     logit diff ≤ 1e-6 vs the dense source (bitwise in practice), including
     with zero-padded wider experts (new columns compute exactly 0)."""
     op = upcycle_operator(DENSE, cfg2)
-    big = apply_ligo(op, dense_params, DENSE, cfg2, engine=engine)
+    big = grow_fn(op, dense_params, DENSE, cfg2)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
                               DENSE.vocab_size)
     lg1 = _logits(dense_params, DENSE, toks)
@@ -92,7 +94,7 @@ def test_upcycle_sharded_apply_matches_legacy(mesh_factory, dense_params,
     legacy walk's tree bitwise on the 8-virtual-device lane."""
     mesh = mesh_factory(*mesh_def)
     op = upcycle_operator(DENSE, MOE)
-    ref = apply_ligo(op, dense_params, DENSE, MOE, engine="legacy")
+    ref = apply_ligo_reference(op, dense_params, DENSE, MOE)
     plan = plan_for(DENSE, MOE, dense_params)
     big = plan.executor(mesh=mesh)(place_operator(op, mesh), dense_params)
     ref_l = jax.tree_util.tree_leaves_with_path(ref)
@@ -136,8 +138,8 @@ def test_upcycle_through_grow_dispatch(dense_params):
 def test_gqa_merge_matches_group_mean_oracle():
     params = init_params(MHA, jax.random.PRNGKey(3))
     op = gqa_merge_operator(MHA, GQA)
-    big_p = apply_ligo(op, params, MHA, GQA, engine="plan")
-    big_l = apply_ligo(op, params, MHA, GQA, engine="legacy")
+    big_p = apply_ligo(op, params, MHA, GQA)
+    big_l = apply_ligo_reference(op, params, MHA, GQA)
     for a, b in zip(jax.tree.leaves(big_p), jax.tree.leaves(big_l)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
     dh, G = MHA.d_head, MHA.n_heads // GQA.n_kv_heads
